@@ -68,48 +68,6 @@ def measure(law, grid=8, n_per_col=60, steps=400, reps=3,
     }
 
 
-def measure_distributed(devices=8, grid=8, n_per_col=60, steps=300) -> dict:
-    """Same metric with the REAL distributed engine (halo exchange over
-    host devices) -- runs in a subprocess so the device count does not
-    leak into the caller."""
-    import json as _json
-    import os
-    import subprocess
-    import sys
-    code = f"""
-import json
-import jax
-from repro.core.connectivity import gaussian_law, exponential_law
-from repro.core.grid import ColumnGrid, TileDecomposition
-from repro.core.engine import EngineConfig
-from repro.core.dist_engine import DistConfig, simulate
-from repro.parallel.compat import make_mesh
-mesh = make_mesh((4, 2), ("data", "model"))
-out = {{}}
-for name, law in (("gaussian", gaussian_law()),
-                  ("exponential", exponential_law())):
-    dec = TileDecomposition(grid=ColumnGrid({grid}, {grid}, {n_per_col}),
-                            tiles_y=4, tiles_x=2, radius=law.radius)
-    cfg = DistConfig(engine=EngineConfig(decomp=dec, law=law))
-    r = simulate(cfg, mesh, n_steps={steps}, timed=True)
-    ev = max(r["events_timed"], 1)
-    out[name] = dict(elapsed_s=r["elapsed_s"], events=ev,
-                     rate_hz=r["rate_hz"],
-                     cost_per_event=r["elapsed_s"] / ev)
-print("JSON:" + json.dumps(out))
-"""
-    env = dict(os.environ,
-               XLA_FLAGS=f"--xla_force_host_platform_device_count={devices}",
-               PYTHONPATH=os.path.join(os.path.dirname(os.path.dirname(
-                   os.path.abspath(__file__))), "src"))
-    r = subprocess.run([sys.executable, "-c", code], env=env,
-                       capture_output=True, text=True, timeout=1800)
-    if r.returncode != 0:
-        return {"error": r.stderr[-500:]}
-    payload = [l for l in r.stdout.splitlines() if l.startswith("JSON:")]
-    return _json.loads(payload[0][5:])
-
-
 def analytic_fullscale(shards=1024, grid=96) -> dict:
     """TPU-target roofline model at the paper's scale."""
     from repro.core.grid import ColumnGrid, TileDecomposition
@@ -315,7 +273,7 @@ def bench_event_delivery(grid=8, n_per_col=60, steps=300,
     return out
 
 
-def run_bench(grid=8, steps=400, with_distributed=True) -> dict:
+def run_bench(grid=8, steps=400) -> dict:
     g = measure(gaussian_law(), grid=grid, steps=steps)
     e = measure(exponential_law(), grid=grid, steps=steps)
     out = {
@@ -336,13 +294,6 @@ def run_bench(grid=8, steps=400, with_distributed=True) -> dict:
             "opposite sign -- a substrate win the paper's own scaling "
             "question makes visible."),
     }
-    if with_distributed:
-        d = measure_distributed(grid=grid, steps=steps)
-        out["distributed_8dev"] = d
-        if "gaussian" in d:
-            out["cost_ratio_distributed"] = (
-                d["exponential"]["cost_per_event"]
-                / d["gaussian"]["cost_per_event"])
     # update_root=False: the Fig-2 run reports the A/B but must not
     # silently rewrite the committed regression-guard baseline --
     # refreshing that is an explicit bench_event_delivery() run.
@@ -363,9 +314,6 @@ def main():
           f"(rate {e['rate_hz']:.1f} Hz, {e['synapses']} syn)")
     print(f"cost ratio exp/gauss (single shard): "
           f"{out['cost_ratio_single_shard']:.2f}")
-    if "cost_ratio_distributed" in out:
-        print(f"cost ratio exp/gauss (8-device halo): "
-              f"{out['cost_ratio_distributed']:.2f}")
     for name, ab in out["event_delivery_ab"]["laws"].items():
         print(f"{name}: kernel/xla wall ratio "
               f"{ab['kernel_vs_xla_wall_ratio']:.2f} "

@@ -5,20 +5,20 @@ VMEM under specific alignment facts: the entry stream is lane-packed
 as ``(E/128, 128)`` so ``LANES`` is pinned to the TPU lane width,
 block minor dims must be multiples of 128 (or 1) and second-minor
 multiples of 8 (or 1) to match Mosaic's (8, 128) f32 tiling, and the
-two-level one-hot MXU factors scale with ``d_ring * TILE_N`` --
-``{ENTRY_BLOCK: 64, TILE_N: 4096}``-style constants would compile to
-an ~18 MiB block and fail on real hardware.  Checks, per module under
-``kernels/``:
+per-row one-hot MXU factors scale with the ring tile ``d_ring *
+TILE_N``.  Checks, per module under ``kernels/``:
 
 * ``LANES == 128``; ``TILE_N`` / ``OUT_TILE`` / ``CHUNK`` divisible by
   ``LANES``; ``ENTRY_BLOCK == ENTRY_SUBLANES * LANES``;
 * every ``pl.BlockSpec((a, b), ...)`` with statically-foldable dims:
   ``b % 128 == 0`` (or ``b == 1``) and ``a % 8 == 0`` (or ``a == 1``);
-* the one-hot factor footprint at the engine's default ``d_ring``
-  (read from ``EngineConfig``) stays under the ~16 MiB VMEM budget --
-  both the ring-tiled delivery layout (``ENTRY_BLOCK`` x ``TILE_N``)
-  and the fused plastic step's resident-ring layout (``CHUNK`` x
-  ``RING_N_MAX``, the whole ring live across grid steps).
+* the VMEM footprint at the engine's default ``d_ring`` (read from
+  ``EngineConfig``) stays under the ~16 MiB VMEM budget -- both the
+  ring-tiled delivery layout (a ``(d_ring, TILE_N)`` ring tile) and the
+  fused plastic step's resident-ring layout (the whole ``(d_ring,
+  RING_N_MAX)`` ring live across grid steps).  This is an estimate;
+  ``tests/test_tpu_compile.py`` has the TPU compiler check the real
+  kernels.
 """
 
 from __future__ import annotations
@@ -130,50 +130,53 @@ class PallasGeometryChecker(Checker):
                     f"BlockSpec second-minor dim {second} is not a "
                     f"multiple of {_SUBLANE} (f32 sublane tile)")
 
-    # ---- one-hot factor VMEM footprint --------------------------------
+    # ---- kernel VMEM footprint ----------------------------------------
     def _vmem_budget(self, mod: Module, env: Dict[str, int],
                      d_ring: int) -> Iterable[Finding]:
         lanes = env.get("LANES", _LANE)
         if not lanes:
             return
         f32 = 4
+        lane_onehot = lanes * lanes * f32
         eb, tile_n = env.get("ENTRY_BLOCK"), env.get("TILE_N")
         if eb is not None and tile_n is not None:
-            # ring-tiled delivery kernel: the block streams ENTRY_BLOCK
-            # entries against a (d_ring, TILE_N) ring tile
-            row_onehot = eb * (d_ring * tile_n // lanes) * f32
-            lane_onehot = eb * lanes * f32
-            ring_tiles = 2 * d_ring * tile_n * f32
-            entry_blocks = 3 * eb * f32
+            # ring-tiled delivery kernel: each 128-entry row builds a
+            # (d_ring * TILE_N / LANES, LANES) row one-hot and adds its
+            # product into a sum of that shape (three such live at
+            # once), against a (d_ring, TILE_N) ring tile in and out;
+            # pipelined inputs and outputs are double-buffered
+            row_onehot = 3 * d_ring * tile_n * f32
+            ring_tiles = 2 * 2 * d_ring * tile_n * f32
+            entry_blocks = 2 * 3 * eb * f32
             total = row_onehot + lane_onehot + ring_tiles + entry_blocks
             if total > VMEM_BUDGET_BYTES:
                 yield Finding(
                     mod.path, 1, self.name,
-                    f"one-hot MXU factors at ENTRY_BLOCK={eb}, "
-                    f"TILE_N={tile_n}, d_ring={d_ring} need "
+                    f"delivery kernel at ENTRY_BLOCK={eb}, "
+                    f"TILE_N={tile_n}, d_ring={d_ring} needs "
                     f"~{total / 2**20:.1f} MiB of VMEM "
                     f"(budget {VMEM_BUDGET_BYTES / 2**20:.0f} MiB): "
-                    "shrink ENTRY_BLOCK or TILE_N")
+                    "shrink TILE_N or ENTRY_BLOCK")
         rnm, chunk = env.get("RING_N_MAX"), env.get("CHUNK")
         if rnm is not None and chunk is not None:
             # resident-ring fused plastic kernel: the whole
             # (d_ring, RING_N_MAX) ring (in + accumulator) stays in
-            # VMEM across grid steps, and each liveness-gated CHUNK
-            # contracts a (CHUNK, d_ring * RING_N_MAX / LANES) one-hot
-            # row factor against the lane-packed weights; 5 entry
-            # streams (tgt/w/d/mask in, depressed w out) ride along
-            row_onehot = chunk * (d_ring * rnm // lanes) * f32
-            lane_onehot = chunk * lanes * f32
-            rings = 2 * d_ring * rnm * f32
-            xpost = rnm * f32
-            streams = 5 * (eb or chunk) * f32
+            # VMEM across grid steps, and each 128-entry row contracts
+            # a (d_ring * RING_N_MAX / LANES, LANES) row one-hot
+            # against it; the LTD gather holds the post-trace and a
+            # one-hot of its size; 5 entry streams (tgt/w/d/mask in,
+            # depressed w out) ride along, double-buffered
+            row_onehot = 3 * d_ring * rnm * f32
+            rings = 2 * 2 * d_ring * rnm * f32
+            xpost = 3 * rnm * f32
+            streams = 2 * 5 * (eb or chunk) * f32
             total = row_onehot + lane_onehot + rings + xpost + streams
             if total > VMEM_BUDGET_BYTES:
                 yield Finding(
                     mod.path, 1, self.name,
-                    f"resident-ring plastic kernel at CHUNK={chunk}, "
+                    f"resident-ring plastic kernel at "
                     f"RING_N_MAX={rnm}, d_ring={d_ring} needs "
                     f"~{total / 2**20:.1f} MiB of VMEM "
                     f"(budget {VMEM_BUDGET_BYTES / 2**20:.0f} MiB): "
                     "shrink RING_N_MAX (larger shards take the "
-                    "two-pass fallback) or CHUNK")
+                    "two-pass fallback)")

@@ -39,19 +39,10 @@ import jax.numpy as jnp
 AxisName = Union[str, Sequence[str]]
 
 
-def _one_axis_size(a) -> int:
-    if hasattr(jax.lax, "axis_size"):
-        return jax.lax.axis_size(a)
-    return jax.lax.psum(1, a)      # static int on pre-axis_size jax
-
-
 def _axis_size(axis_name: AxisName) -> int:
     if isinstance(axis_name, (tuple, list)):
-        s = 1
-        for a in axis_name:
-            s *= _one_axis_size(a)
-        return s
-    return _one_axis_size(axis_name)
+        return math.prod(jax.lax.axis_size(a) for a in axis_name)
+    return jax.lax.axis_size(axis_name)
 
 
 def shift(x: jnp.ndarray, axis_name: AxisName, k: int) -> jnp.ndarray:

@@ -125,7 +125,7 @@ class TierPlan:
     active_cap: int    # event-compaction list size
     rows: int          # source rows (excluding the sink row)
     entries: int       # active_cap * cap
-    entries_padded: int  # entries, lane-aligned
+    entries_padded: int  # active_cap * cap rounded up to whole lane rows
 
 
 @dataclasses.dataclass(frozen=True)
@@ -436,14 +436,13 @@ class SynapseTableSpec:
         materialized (possibly compressed) caps rather than the
         analytic ones.
         """
-        from ..kernels.synaptic_accum import LANES  # layout owner
+        from ..kernels.synaptic_accum import row_lanes  # layout owner
         st = self._storage(storage)
 
         def tier(cap, active_cap, rows):
-            entries = active_cap * cap
             return TierPlan(cap=cap, active_cap=active_cap, rows=rows,
-                            entries=entries,
-                            entries_padded=-(-entries // LANES) * LANES)
+                            entries=active_cap * cap,
+                            entries_padded=active_cap * row_lanes(cap))
 
         plan = [tier(st.cap_local, self.active_cap_local, self.n_local)]
         for b, cap in zip(self.halo_bands(), st.halo_caps):
@@ -753,7 +752,9 @@ def deliver_events(tables: dict, spikes_src: jnp.ndarray,
     rows_w = w[idx].astype(jnp.float32)
     rows_d = dslot[idx].astype(jnp.int32)
     slots = (t_slot + rows_d) % d_ring
-    i_ring = i_ring.at[slots.ravel(), rows_t.ravel()].add(rows_w.ravel())
+    # 2-D indices: flattening (A, cap) with cap off the 128-lane grid is
+    # a relayout the TPU compiler spends minutes on
+    i_ring = i_ring.at[slots, rows_t].add(rows_w)
     n_spikes = jnp.sum(spk > 0)
     n_events = jnp.sum(nnz[idx])
     n_dropped = jnp.maximum(n_spikes - active_cap, 0)

@@ -23,12 +23,7 @@ import math
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-try:
-    from jax.experimental.pallas import tpu as pltpu
-    _VMEM = pltpu.VMEM
-except Exception:  # pragma: no cover
-    _VMEM = None
+from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
 
@@ -44,12 +39,14 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    q_pos = q_offset + qi * block_q + jax.lax.iota(jnp.int32, block_q)
-    k_pos = ki * block_k + jax.lax.iota(jnp.int32, block_k)
+    # block bounds as scalars; element positions as 2-D iotas (Mosaic
+    # lowers neither 1-D iotas nor indexing into them)
+    q_first = q_offset + qi * block_q
+    q_last = q_first + block_q - 1
+    k_first = ki * block_k
+    k_last = k_first + block_k - 1
 
     # block-level liveness: any (q, k) pair in this tile unmasked?
-    q_last, q_first = q_pos[-1], q_pos[0]
-    k_first, k_last = k_pos[0], k_pos[-1]
     live = k_first < k_valid
     if causal:
         live = jnp.logical_and(live, k_first <= q_last)
@@ -63,12 +60,15 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
         v = v_ref[0].astype(jnp.float32)
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32) * scale
-        mask = (k_pos < k_valid)[None, :] & jnp.ones(
-            (block_q, block_k), dtype=bool)
+        q_pos = q_first + jax.lax.broadcasted_iota(
+            jnp.int32, (block_q, block_k), 0)
+        k_pos = k_first + jax.lax.broadcasted_iota(
+            jnp.int32, (block_q, block_k), 1)
+        mask = k_pos < k_valid
         if causal:
-            mask &= k_pos[None, :] <= q_pos[:, None]
+            mask &= k_pos <= q_pos
         if window is not None:
-            mask &= (q_pos[:, None] - k_pos[None, :]) < window
+            mask &= (q_pos - k_pos) < window
         s = jnp.where(mask, s, NEG_INF)
 
         m_prev, l_prev = m_ref[...], l_ref[...]
@@ -93,7 +93,7 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
 
 def flash_attention(q, k, v, *, causal=True, window=None, scale=None,
                     q_offset=0, block_q=128, block_k=128,
-                    interpret: bool = True):
+                    interpret: bool):
     """q: (BH, Sq, D); k, v: (BH_kv, Sk, D); BH % BH_kv == 0 (GQA).
 
     Returns (BH, Sq, D) in q.dtype.  Matches ``ref.attention_ref``.
@@ -130,10 +130,13 @@ def flash_attention(q, k, v, *, causal=True, window=None, scale=None,
         out_specs=q_spec,
         out_shape=jax.ShapeDtypeStruct((bh, sq + pad_q, d), q.dtype),
         scratch_shapes=[
-            _VMEM((block_q, 1), jnp.float32),
-            _VMEM((block_q, 1), jnp.float32),
-            _VMEM((block_q, d), jnp.float32),
+            pltpu.VMEM((block_q, 1), jnp.float32),
+            pltpu.VMEM((block_q, 1), jnp.float32),
+            pltpu.VMEM((block_q, d), jnp.float32),
         ],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
+        name="flash_attention",
     )(q, k, v)
     return out[:, :sq, :]

@@ -18,6 +18,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 LANES = 128
 DEFAULT_BLOCK_ROWS = 512
@@ -46,8 +47,8 @@ def _kernel(v_ref, c_ref, r_ref, i_ref, a_ref,
 
 def lif_step_pallas(v, c, refrac, i_total, active, *, leak_decay, sfa_decay,
                     g_sfa, dt_ms, v_rest, v_reset, theta, alpha_c,
-                    refrac_steps, block_rows: int = DEFAULT_BLOCK_ROWS,
-                    interpret: bool = True):
+                    refrac_steps, interpret: bool,
+                    block_rows: int = DEFAULT_BLOCK_ROWS):
     """Fused update on flat (n,) state arrays.  Returns (v, c, refrac, spk)."""
     n = v.shape[0]
     # clamp the block to the problem: small nets (tests, reduced grids)
@@ -80,6 +81,9 @@ def lif_step_pallas(v, c, refrac, i_total, active, *, leak_decay, sfa_decay,
             jax.ShapeDtypeStruct((rows, LANES), jnp.int32),
             jax.ShapeDtypeStruct((rows, LANES), jnp.float32),
         ],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel",)),
         interpret=interpret,
+        name="lif_step",
     )(*args)
     return tuple(x.reshape(-1)[:n] for x in out)

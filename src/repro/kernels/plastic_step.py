@@ -37,12 +37,12 @@ kernel-vs-XLA bit-identity tests already pin down.
 The resident ring caps the supported shard size: ``n_local`` padded to
 ``N_ALIGN`` must stay within ``RING_N_MAX`` (covers the committed
 acceptance configs -- 8x8x60 pads to 4096 -- and any shard up to 8192
-local neurons; at d_ring=8 the (CHUNK, d_ring * RING_N_MAX / LANES)
-one-hot row factor is 8 MiB and the whole working set ~10.8 MiB,
-inside the ~16 MiB VMEM core -- the ``pallas-geometry`` repro-lint
-pass re-derives this bound from the module constants).  Larger shards
-fall back to the two-pass path -- ``fused_supported`` is the routing
-predicate -- which is bit-identical, just slower.
+local neurons).  Every 128-entry row contracts a (d_ring * n_pad /
+LANES, LANES) one-hot against the whole ring, so the MXU work per
+entry grows with the ring; ``tests/test_tpu_compile.py`` compiles the
+kernel at ``RING_N_MAX`` for a v5e.  Larger shards fall back to the
+two-pass path -- ``fused_supported`` is the routing predicate -- which
+is bit-identical, just slower.
 """
 
 from __future__ import annotations
@@ -55,13 +55,13 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .synaptic_accum import (LANES, _ceil_to, _gather_entries, _pad_flat,
-                             compact_events)
+from .synaptic_accum import (_SUB, LANES, N_ALIGN, _ceil_to,
+                             _gather_entries, _pad_flat, compact_events,
+                             gather_rows, row_lanes, scatter_rows)
 
 ENTRY_SUBLANES = 128        # sublanes per entry block (vs 32 for delivery)
 ENTRY_BLOCK = ENTRY_SUBLANES * LANES   # 16384 entries per grid step
 CHUNK = 4096                # entries per in-body liveness-gated chunk
-N_ALIGN = 8 * LANES         # ring width alignment (sublane-tiled x_post)
 RING_N_MAX = 8192           # max padded n_local the resident ring holds
 
 _CSUB = CHUNK // LANES      # sublanes per chunk
@@ -91,9 +91,10 @@ def _plastic_kernel(neg_a_minus: float, d_ring: int,
                   CHUNK-entry chunk (count of live entries; 0 skips)
     tgt/w/d/m:    (ENTRY_SUBLANES, LANES) lane-packed entry block
                   (target id, weight, delay slot, plastic mask)
-    ring/xpost:   full-resident (d_ring, n_pad) ring and the decayed
-                  post-trace repacked (n_pad / LANES, LANES)
-    out_ring:     (d_ring, n_pad) accumulator, resident across blocks
+    ring:         full-resident (d_ring, n_hi, LANES) ring
+    xpost_ref:    the decayed post-trace, transposed: (LANES, n_hi)
+    out_ring:     (d_ring, n_hi, LANES) accumulator, resident across
+                  blocks
     out_w:        (ENTRY_SUBLANES, LANES) depressed-weight stream
     """
     e = pl.program_id(0)
@@ -106,53 +107,46 @@ def _plastic_kernel(neg_a_minus: float, d_ring: int,
     # so the host-side scatter of event rows needs no liveness mask.
     out_w_ref[...] = w_ref[...]
 
-    n_pad = out_ring_ref.shape[1]
-    n_hi = n_pad // LANES
+    n_hi = out_ring_ref.shape[1]
     t0 = meta_ref[0]
+
+    def ltd(r0, hi, lo):
+        # LTD: exact one-hot gather of x_post[tgt] (x_post >= 0 and each
+        # sum has a single nonzero term, so the gathered value is
+        # bitwise x_post[tgt]), then the reference's association
+        # ((-a_minus) * x) * mask.  mask == 0 (non-plastic + padding)
+        # yields dw = -0.0, and w + (-0.0) == w bitwise for every
+        # float32 w.
+        sub = jax.lax.broadcasted_iota(jnp.int32, (_SUB, LANES), 0)
+        xg = jnp.zeros((_SUB, LANES), jnp.float32)
+        for r in range(_SUB):
+            oh_hi = (hi[r:r + 1] == jax.lax.broadcasted_iota(
+                jnp.int32, (n_hi, LANES), 0)).astype(jnp.float32)
+            xcols = jax.lax.dot_general(
+                xpost_ref[...], oh_hi,
+                dimension_numbers=(((1,), (0,)), ((), ())),
+                precision=jax.lax.Precision.HIGHEST,
+                preferred_element_type=jnp.float32)   # (LANES, entries)
+            x_r = jnp.sum(jnp.where(
+                lo[r:r + 1] == jax.lax.broadcasted_iota(
+                    jnp.int32, (LANES, LANES), 0), xcols, 0.0),
+                axis=0, keepdims=True)                # (1, entries)
+            xg = jnp.where(sub == r, x_r, xg)
+        dw = (neg_a_minus * xg) * m_ref[pl.ds(r0, _SUB), :]
+        out_w_ref[pl.ds(r0, _SUB), :] = w_ref[pl.ds(r0, _SUB), :] + dw
 
     @pl.when(blk_ref[e] > 0)
     def _block():
         for c in range(_NCHUNK):
             @pl.when(chk_ref[e * _NCHUNK + c] > 0)
             def _chunk(c=c):
-                sl = slice(c * _CSUB, (c + 1) * _CSUB)
-                tgt = tgt_ref[sl, :].reshape(CHUNK, 1)
-                w = w_ref[sl, :].reshape(CHUNK, 1)
-                mask = m_ref[sl, :].reshape(CHUNK, 1)
-                slots = (t0 + d_ref[sl, :].reshape(CHUNK, 1)) % d_ring
-                hi = jnp.floor_divide(tgt, LANES)             # sublane grp
-                lo = tgt - hi * LANES                         # lane
-                oh_lane = lo == jax.lax.broadcasted_iota(
-                    jnp.int32, (CHUNK, LANES), 1)
-                # -- delivery: identical two-level one-hot contraction
-                # (and 4096-entry reduction grouping) to the delivery
-                # kernel; padding entries carry w == 0 and contribute
-                # an exact +0.0.
-                rid = slots * n_hi + hi                       # (slot, hi)
-                oh_row = rid == jax.lax.broadcasted_iota(
-                    jnp.int32, (CHUNK, d_ring * n_hi), 1)
-                contrib = jax.lax.dot_general(
-                    oh_row.astype(jnp.float32),
-                    jnp.where(oh_lane, w, 0.0),
-                    dimension_numbers=(((0,), (0,)), ((), ())),
-                    preferred_element_type=jnp.float32)       # (R, LANES)
-                out_ring_ref[...] += contrib.reshape(d_ring, n_pad)
-                # -- LTD: exact one-hot gather of x_post[tgt] (x_post
-                # >= 0 and the row sum has a single nonzero term, so
-                # the reduction is bitwise the gathered value), then
-                # the reference's association ((-a_minus) * x) * mask.
-                # mask == 0 (non-plastic + padding) yields dw = -0.0,
-                # and w + (-0.0) == w bitwise for every float32 w.
-                oh_hi = hi == jax.lax.broadcasted_iota(
-                    jnp.int32, (CHUNK, n_hi), 1)
-                xrows = jax.lax.dot_general(
-                    oh_hi.astype(jnp.float32), xpost_ref[...],
-                    dimension_numbers=(((1,), (0,)), ((), ())),
-                    preferred_element_type=jnp.float32)       # (CHUNK, L)
-                xg = jnp.sum(jnp.where(oh_lane, xrows, 0.0), axis=1,
-                             keepdims=True)                   # (CHUNK, 1)
-                dw = (neg_a_minus * xg) * mask
-                out_w_ref[sl, :] += dw.reshape(_CSUB, LANES)
+                # delivery: the delivery kernel's row contraction and
+                # CHUNK-entry reduction grouping (padding entries carry
+                # w == 0 and contribute an exact +0.0), with LTD riding
+                # the same row loads
+                acc = scatter_rows(tgt_ref, w_ref, d_ref, c * _CSUB, _CSUB,
+                                   t0, 0, d_ring, n_hi, rows_hook=ltd)
+                out_ring_ref[...] += acc.reshape(d_ring, n_hi, LANES)
 
 
 def _chunk_liveness(w_e, m_e):
@@ -173,7 +167,7 @@ def plastic_delivery_ltd(tiers: Sequence[Tuple[dict, jnp.ndarray, int]],
                          masks: Sequence[jnp.ndarray],
                          x_post_decayed: jnp.ndarray,
                          i_ring, t_slot, d_ring: int, neg_a_minus: float,
-                         *, plan=None, interpret: bool = True):
+                         *, plan=None, interpret: bool):
     """Fused delivery + LTD over every tier in ONE kernel launch.
 
     ``tiers``: [(tables, spikes_src, active_cap)] with ``tables["w"]``
@@ -215,18 +209,14 @@ def plastic_delivery_ltd(tiers: Sequence[Tuple[dict, jnp.ndarray, int]],
                     f"active_cap={p.active_cap}")
         idx, n_spk = compact_events(spikes_src, n_rows, active_cap)
         te, we, de = _gather_entries(tables, idx)
-        me = masks[ti][idx].astype(jnp.float32).ravel()
-        e_pad = (plan[ti].entries_padded if plan is not None
-                 else _ceil_to(te.shape[0], LANES))
-        te, we, de = _pad_flat(te, we, de, e_pad)
-        me = jnp.pad(me, (0, e_pad - me.shape[0]))
+        me = gather_rows(masks[ti], idx, jnp.float32)
         parts_t.append(te)
         parts_w.append(we)
         parts_d.append(de)
         parts_m.append(me)
         idxs.append(idx)
         offsets.append(off)
-        off += e_pad
+        off += te.shape[0]
         n_events = n_events + jnp.sum(tables["nnz"][idx]).astype(jnp.int32)
         n_dropped = n_dropped + jnp.maximum(
             n_spk - active_cap, 0).astype(jnp.int32)
@@ -245,9 +235,10 @@ def plastic_delivery_ltd(tiers: Sequence[Tuple[dict, jnp.ndarray, int]],
             f"{RING_N_MAX}: the resident-ring plastic kernel does not "
             "cover this shard size -- route through fused_supported()")
     n_hi = n_pad // LANES
-    ring_p = jnp.pad(i_ring, ((0, 0), (0, n_pad - n_local)))
-    xpost_p = jnp.pad(x_post_decayed.astype(jnp.float32),
-                      (0, n_pad - n_local)).reshape(n_hi, LANES)
+    ring_p = jnp.pad(i_ring, ((0, 0), (0, n_pad - n_local))).reshape(
+        d_r, n_hi, LANES)
+    xpost_t = jnp.pad(x_post_decayed.astype(jnp.float32),
+                      (0, n_pad - n_local)).reshape(n_hi, LANES).T
     blk, chk = _chunk_liveness(w_e, m_e)
     meta = jnp.asarray([t_slot], jnp.int32).reshape(1)
     n_blocks = total // ENTRY_BLOCK
@@ -257,8 +248,9 @@ def plastic_delivery_ltd(tiers: Sequence[Tuple[dict, jnp.ndarray, int]],
 
     entry_spec = pl.BlockSpec((ENTRY_SUBLANES, LANES),
                               lambda e, m, bl, ck: (e, 0))
-    ring_spec = pl.BlockSpec((d_r, n_pad), lambda e, m, bl, ck: (0, 0))
-    xpost_spec = pl.BlockSpec((n_hi, LANES), lambda e, m, bl, ck: (0, 0))
+    ring_spec = pl.BlockSpec((d_r, n_hi, LANES),
+                             lambda e, m, bl, ck: (0, 0, 0))
+    xpost_spec = pl.BlockSpec((LANES, n_hi), lambda e, m, bl, ck: (0, 0))
     gspec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3, grid=(n_blocks,),
         in_specs=[entry_spec, entry_spec, entry_spec, entry_spec,
@@ -268,21 +260,27 @@ def plastic_delivery_ltd(tiers: Sequence[Tuple[dict, jnp.ndarray, int]],
     ring_out, w_out = pl.pallas_call(
         kernel,
         grid_spec=gspec,
-        out_shape=[jax.ShapeDtypeStruct((d_r, n_pad), jnp.float32),
+        out_shape=[jax.ShapeDtypeStruct(ring_p.shape, jnp.float32),
                    jax.ShapeDtypeStruct((total // LANES, LANES),
                                         jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
         interpret=interpret,
+        name="plastic_step",
     )(meta, blk, chk, packed(tgt_e, jnp.int32), packed(w_e, jnp.float32),
-      packed(d_e, jnp.int32), packed(m_e, jnp.float32), ring_p, xpost_p)
+      packed(d_e, jnp.int32), packed(m_e, jnp.float32), ring_p, xpost_t)
 
     w_flat = w_out.reshape(-1)
     new_w = []
     for (tables, _, active_cap), idx, off_t in zip(tiers, idxs, offsets):
         cap = tables["tgt"].shape[1]
+        lanes = row_lanes(cap)
         rows_w = jax.lax.dynamic_slice(
-            w_flat, (off_t,), (active_cap * cap,)).reshape(active_cap, cap)
+            w_flat, (off_t,), (active_cap * lanes,)).reshape(
+                active_cap, lanes)[:, :cap]
         # scatter-SET over the compacted (unique) event rows; duplicate
         # sink fills all write the sink row's unchanged 0.0
         new_w.append(tables["w"].at[idx].set(
             rows_w.astype(tables["w"].dtype)))
-    return ring_out[:, :n_local], new_w, n_events, n_dropped
+    return (ring_out.reshape(d_r, n_pad)[:, :n_local], new_w, n_events,
+            n_dropped)

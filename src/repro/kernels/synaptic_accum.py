@@ -29,15 +29,19 @@ together themselves.  The TPU shape of the problem:
     b`` (ring tile, sublane group, lane); the ring tile ``i`` is a grid
     dimension, and within a tile the contribution is
 
-        out[d, a * LANES + b] = sum_e w[e] * [slot[e] == d]
-                                           * [hi[e] == a] * [lo[e] == b]
+        out[d, a, b] = sum_e w[e] * [slot[e] == d] * [hi[e] == a]
+                                  * [lo[e] == b]
 
-    computed as one ``(blk, R) x (blk, LANES)`` matmul with
-    ``R = d_ring * tile_n / LANES``: the left factor one-hots the fused
-    (slot, sublane-group) row id, the right factor carries ``w`` through
-    a lane one-hot.  That shrinks the per-block one-hot footprint from
-    ``(blk, TILE_N)`` (8 MiB at the old sizes) to two ``(blk, 128)``-ish
-    factors while keeping the same per-entry MXU flops.
+    The entry axis stays on lanes throughout: each sublane row of an
+    entry block (``LANES`` entries) builds a ``(R, LANES)`` row one-hot
+    (``R = d_ring * tile_n / LANES``, the fused (slot, sublane-group)
+    row id) and a ``(LANES, LANES)`` lane one-hot carrying ``w``, and
+    one matmul contracting the lanes of both gives the ``(R, LANES)``
+    contribution.  The ring is handed to the kernel as ``(d_ring,
+    n_pad / LANES, LANES)`` so that contribution adds in place; the
+    reshape from and back to ``(d_ring, n_local)`` happens in XLA.
+    Nothing in the kernel moves data between sublanes and lanes, which
+    Mosaic cannot lower for these shapes.
   * **block skipping** is per (ring tile, entry block): scalar-prefetched
     per-block [first, last] target-tile windows (min/max of the live
     ``w != 0`` entries) let ``pl.when`` skip a block on every tile it
@@ -47,15 +51,14 @@ together themselves.  The TPU shape of the problem:
     targets live in ring tile 0 is no longer streamed through every
     other tile.
   * the grid is 2-D ``(n_tiles, n_blocks)`` with the entry-block
-    dimension innermost: each ``(d_ring, tile_n)`` ring tile stays
-    VMEM-resident while every entry block streams past it (the former
-    host-level per-tile ``dynamic_slice`` loop is gone).
+    dimension innermost: each ring tile stays VMEM-resident while every
+    entry block streams past it.
 
+The contraction runs at ``Precision.HIGHEST``: plastic weights are
+float32, and the MXU's default single bf16 pass would round them.
 Interpret mode (CPU) executes the identical BlockSpec tiling and kernel
 body with jnp ops, so tests exercise the same code path that compiles
-on TPU.  (TPU-hardware validation of the lane-packed layout is a
-ROADMAP item; the in-kernel ``(S, L) -> (S*L, 1)`` relayouts are the
-part Mosaic is most likely to want reworked.)
+on TPU; ``tests/test_tpu_compile.py`` compiles it for a described v5e.
 """
 
 from __future__ import annotations
@@ -71,18 +74,15 @@ LANES = 128               # vector lane width: packed entry minor dim
 ENTRY_SUBLANES = 32       # sublanes per entry block
 ENTRY_BLOCK = ENTRY_SUBLANES * LANES   # synapse entries per grid step
 TILE_N = 4096             # max ring-tile width (lane dim, multiple of 128)
+N_ALIGN = 8 * LANES       # ring width alignment: whole (8, 128) tiles
 
-# Sized so the largest kernel intermediates -- the (ENTRY_BLOCK, R) one-
-# hot row factor (4 MiB f32 at d_ring=8 / TILE_N=4096, R = d_ring *
-# TILE_N / LANES = 256) and the (ENTRY_BLOCK, LANES) lane factor
-# (2 MiB) -- plus their bool precursors, the resident ring tile and the
-# entry blocks stay inside a ~16 MiB VMEM core.  (CPU-interpret sweep
-# at the committed 8x8x60 benchmark: {SUB=32,TN=2048}: 7.3/12.1 s per
-# 60 steps gaussian/exponential, {64,2048}: 4.9/6.9, {32,4096}:
-# 3.5/5.7, {64,4096}: 2.6/3.7 but ~18 MiB of intermediates -- {32,4096}
-# is the best point that still fits compiled VMEM.)
+# Per sublane row the kernel holds a (d_ring * TILE_N / LANES, LANES)
+# row one-hot (128 KiB f32 at d_ring=8) and a (LANES, LANES) lane
+# factor; the ring tile in and out is 2 x 128 KiB.
 
 _FAR = 2 ** 30            # min-reduction sentinel for non-live entries
+_SUB = 8                  # sublanes per vreg: rows loaded at a time
+_LANE_BITS = 7            # log2(LANES): tgt = hi * LANES + lo
 
 
 def _ceil_to(x: int, m: int) -> int:
@@ -96,6 +96,69 @@ def packed_total(entries: int) -> int:
     return _ceil_to(max(entries, ENTRY_BLOCK), ENTRY_BLOCK)
 
 
+def row_contrib(rid, lo, w, n_rows: int):
+    """One-hot contraction of one lane-packed entry row.
+
+    ``rid``/``lo``/``w``: (1, LANES) -- each entry's ring row id, lane
+    and weight.  Returns the (n_rows, LANES) f32 contribution
+    ``out[r, b] = sum_e w[e] * [rid[e] == r] * [lo[e] == b]``: the row
+    one-hot and the weighted lane one-hot both keep the entries on
+    lanes, and the matmul contracts those lanes.
+    """
+    oh_row = (rid == jax.lax.broadcasted_iota(
+        jnp.int32, (n_rows, LANES), 0)).astype(jnp.float32)
+    lane_w = jnp.where(lo == jax.lax.broadcasted_iota(
+        jnp.int32, (LANES, LANES), 0), w, 0.0)
+    return jax.lax.dot_general(
+        oh_row, lane_w, dimension_numbers=(((1,), (1,)), ((), ())),
+        precision=jax.lax.Precision.HIGHEST,
+        preferred_element_type=jnp.float32)
+
+
+def split_target(tgt):
+    """``tgt = hi * LANES + lo`` (floor semantics for negative ids)."""
+    return (jnp.right_shift(tgt, _LANE_BITS),
+            jnp.bitwise_and(tgt, LANES - 1))
+
+
+def scatter_rows(tgt_ref, w_ref, d_ref, row0: int, n_rows: int, t0,
+                 base, d_ring: int, n_hi: int, rows_hook=None):
+    """Sum the contributions of entry rows ``[row0, row0 + n_rows)`` to
+    a ring tile of ``n_hi * LANES`` neurons starting at neuron ``base``.
+
+    Returns the (d_ring * n_hi, LANES) f32 sum, row ``d * n_hi + a``
+    holding slot ``d``, neurons ``base + a * LANES + [0, LANES)``.
+    Rows load ``_SUB`` at a time; the sum runs over the rows in order,
+    so every kernel that calls this reduces a block identically.
+    Entries outside the tile are zeroed through ``w``: their fused row
+    id may alias a live (slot, hi) pair, and a zero weight is the one
+    thing that is harmless under aliasing.  ``rows_hook(r0, hi, lo)``,
+    if given, runs once per ``_SUB``-row group with the split of that
+    group's targets (in the tile's frame).
+    """
+    r_out = d_ring * n_hi
+
+    def group(g, acc):
+        r0 = pl.multiple_of(row0 + g * _SUB, _SUB)
+        tgt = tgt_ref[pl.ds(r0, _SUB), :]
+        w = w_ref[pl.ds(r0, _SUB), :].astype(jnp.float32)
+        slots = (t0 + d_ref[pl.ds(r0, _SUB), :]) % d_ring
+        local = tgt - base
+        w = jnp.where(jnp.logical_and(local >= 0, local < n_hi * LANES),
+                      w, 0.0)
+        hi, lo = split_target(local)
+        rid = slots * n_hi + hi
+        for r in range(_SUB):
+            acc = acc + row_contrib(rid[r:r + 1], lo[r:r + 1],
+                                    w[r:r + 1], r_out)
+        if rows_hook is not None:
+            rows_hook(r0, hi, lo)
+        return acc
+
+    return jax.lax.fori_loop(0, n_rows // _SUB, group,
+                             jnp.zeros((r_out, LANES), jnp.float32))
+
+
 def _accum_kernel(meta_ref, tmin_ref, tmax_ref, tgt_ref, w_ref, d_ref,
                   ring_ref, out_ref):
     """One (ring-tile, entry-block) grid step of the two-level scatter.
@@ -105,7 +168,7 @@ def _accum_kernel(meta_ref, tmin_ref, tmax_ref, tgt_ref, w_ref, d_ref,
                  tile targeted by the block's live (w != 0) entries;
                  all-padding blocks carry an empty window (tmin > tmax)
     tgt/w/d:     (ENTRY_SUBLANES, LANES) lane-packed entry block
-    ring/out:    (d_ring, tile_n) -- the accumulator tile, resident
+    ring/out:    (d_ring, n_hi, LANES) -- the accumulator tile, resident
                  across the inner (entry-block) grid dimension
     """
     i = pl.program_id(0)              # ring tile
@@ -117,31 +180,10 @@ def _accum_kernel(meta_ref, tmin_ref, tmax_ref, tgt_ref, w_ref, d_ref,
 
     @pl.when(jnp.logical_and(tmin_ref[e] <= i, i <= tmax_ref[e]))
     def _accum():
-        d_ring, tile_n = out_ref.shape
-        n_hi = tile_n // LANES
-        blk = tgt_ref.shape[0] * tgt_ref.shape[1]
-        t0 = meta_ref[0]
-        tgt = tgt_ref[...].reshape(blk, 1) - i * tile_n   # this tile's frame
-        w = w_ref[...].reshape(blk, 1).astype(jnp.float32)
-        slots = (t0 + d_ref[...].reshape(blk, 1)) % d_ring
-        # Out-of-tile entries must be zeroed through w: their fused row
-        # id below may alias a live (slot, hi) pair, and a zero weight
-        # is the one thing that is harmless under aliasing.
-        in_tile = jnp.logical_and(tgt >= 0, tgt < tile_n)
-        w = jnp.where(in_tile, w, 0.0)
-        hi = jnp.floor_divide(tgt, LANES)                 # sublane group
-        lo = tgt - hi * LANES                             # lane
-        rid = slots * n_hi + hi                           # fused (slot, hi)
-        oh_row = rid == jax.lax.broadcasted_iota(
-            jnp.int32, (blk, d_ring * n_hi), 1)
-        oh_lane = lo == jax.lax.broadcasted_iota(
-            jnp.int32, (blk, LANES), 1)
-        contrib = jax.lax.dot_general(
-            oh_row.astype(jnp.float32), jnp.where(oh_lane, w, 0.0),
-            dimension_numbers=(((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)           # (R, LANES)
-        # (d * n_hi + a, b) -> (d, a * LANES + b): row-major reshape.
-        out_ref[...] += contrib.reshape(d_ring, tile_n)
+        d_ring, n_hi, _ = out_ref.shape
+        acc = scatter_rows(tgt_ref, w_ref, d_ref, 0, tgt_ref.shape[0],
+                           meta_ref[0], i * (n_hi * LANES), d_ring, n_hi)
+        out_ref[...] += acc.reshape(d_ring, n_hi, LANES)
 
 
 def _block_tile_windows(tgt_e, w_e, tile_n: int):
@@ -171,11 +213,13 @@ def _scatter_entries(tgt_e, w_e, d_e, ring, t_slot, *,
     (ring tile, entry block) pair on a 2-D grid.
     """
     d_ring, n_local = ring.shape
-    n_pad = _ceil_to(max(n_local, LANES), LANES)
+    n_pad = _ceil_to(max(n_local, N_ALIGN), N_ALIGN)
     tile_n = min(TILE_N, n_pad)
     n_tiles = -(-n_pad // tile_n)
     n_pad = n_tiles * tile_n
-    ring_p = jnp.pad(ring, ((0, 0), (0, n_pad - n_local)))
+    n_hi = tile_n // LANES
+    ring_p = jnp.pad(ring, ((0, 0), (0, n_pad - n_local))).reshape(
+        d_ring, n_pad // LANES, LANES)
     tmin, tmax = _block_tile_windows(tgt_e, w_e, tile_n)
     meta = jnp.asarray([t_slot], jnp.int32).reshape(1)
     n_blocks = tgt_e.shape[0] // ENTRY_BLOCK
@@ -185,8 +229,8 @@ def _scatter_entries(tgt_e, w_e, d_e, ring, t_slot, *,
 
     entry_spec = pl.BlockSpec((ENTRY_SUBLANES, LANES),
                               lambda i, e, m, lo, hi: (e, 0))
-    ring_spec = pl.BlockSpec((d_ring, tile_n),
-                             lambda i, e, m, lo, hi: (0, i))
+    ring_spec = pl.BlockSpec((d_ring, n_hi, LANES),
+                             lambda i, e, m, lo, hi: (0, i, 0))
     gspec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3, grid=(n_tiles, n_blocks),
         in_specs=[entry_spec, entry_spec, entry_spec, ring_spec],
@@ -194,11 +238,14 @@ def _scatter_entries(tgt_e, w_e, d_e, ring, t_slot, *,
     out = pl.pallas_call(
         _accum_kernel,
         grid_spec=gspec,
-        out_shape=jax.ShapeDtypeStruct((d_ring, n_pad), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct(ring_p.shape, jnp.float32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
+        name="synaptic_accum",
     )(meta, tmin, tmax, packed(tgt_e, jnp.int32), packed(w_e, jnp.float32),
       packed(d_e, jnp.int32), ring_p)
-    return out[:, :n_local]
+    return out.reshape(d_ring, n_pad)[:, :n_local]
 
 
 # ---------------------------------------------------------------------------
@@ -221,12 +268,30 @@ def compact_events(spikes_src, n_rows: int, active_cap: int):
 # Stage 2+3: gather event rows and flatten to entry streams
 # ---------------------------------------------------------------------------
 
+def row_lanes(cap: int) -> int:
+    """Entries one gathered event row occupies in the entry stream: its
+    ``cap`` entries padded to whole lane rows."""
+    return _ceil_to(cap, LANES)
+
+
+def gather_rows(table, idx, dtype):
+    """Gather event rows of one table leaf and flatten them row-major to
+    an (A * row_lanes(cap),) stream, each row zero-padded to whole lane
+    rows.  Padding entries carry zeros, which every consumer treats as
+    no-ops (w == 0, mask == 0).  The padding is what lets XLA flatten
+    the gathered rows: an (A, cap) -> (A * cap,) reshape with ``cap`` not
+    a lane multiple is a relayout that takes the TPU compiler minutes at
+    full column width."""
+    rows = table[idx].astype(dtype)
+    cap = rows.shape[1]
+    return jnp.pad(rows, ((0, 0), (0, row_lanes(cap) - cap))).ravel()
+
+
 def _gather_entries(tables: dict, idx):
-    """Gather event rows and flatten row-major to (A * cap,) streams."""
-    rows_t = tables["tgt"][idx]
-    rows_w = tables["w"][idx].astype(jnp.float32)
-    rows_d = tables["dslot"][idx].astype(jnp.int32)
-    return rows_t.astype(jnp.int32).ravel(), rows_w.ravel(), rows_d.ravel()
+    """Gather event rows into lane-padded (tgt, w, dslot) streams."""
+    return (gather_rows(tables["tgt"], idx, jnp.int32),
+            gather_rows(tables["w"], idx, jnp.float32),
+            gather_rows(tables["dslot"], idx, jnp.int32))
 
 
 def _pad_flat(te, we, de, n: int):
@@ -243,8 +308,7 @@ def _pad_flat(te, we, de, n: int):
 # ---------------------------------------------------------------------------
 
 def event_delivery(tables: dict, spikes_src, i_ring, t_slot,
-                   d_ring: int, active_cap: int, *,
-                   interpret: bool = True):
+                   d_ring: int, active_cap: int, *, interpret: bool):
     """Fused single-tier delivery.  Drop-in for
     ``core.synapses.deliver_events``: returns (ring, n_events, n_dropped).
     """
@@ -255,8 +319,7 @@ def event_delivery(tables: dict, spikes_src, i_ring, t_slot,
 
 def event_delivery_banded(tiers: Sequence[Tuple[dict, jnp.ndarray, int]],
                           i_ring, t_slot, d_ring: int, *,
-                          plan=None,
-                          interpret: bool = True):
+                          plan=None, interpret: bool):
     """Fused multi-tier delivery: ONE kernel launch for the local table
     plus every halo fan-out band across every ring tile.
 
@@ -266,8 +329,9 @@ def event_delivery_banded(tiers: Sequence[Tuple[dict, jnp.ndarray, int]],
     ``plan``: optional per-tier ``TierPlan`` sequence from
     ``SynapseTableSpec.delivery_plan()``; when given, the tables are
     validated against it (the typed spec contract the engines compile
-    against) and its lane-padded ``entries_padded`` sizes the per-tier
-    slice of the packed entry stream.  For compressed tables, pass the
+    against); each tier's slice of the packed entry stream is its
+    ``entries_padded`` (rows padded to whole lane rows, ``gather_rows``).
+    For compressed tables, pass the
     plan derived from the tables' ``storage`` descriptor.
     Returns (ring, n_events, n_dropped) summed over tiers.
     """
@@ -292,9 +356,6 @@ def event_delivery_banded(tiers: Sequence[Tuple[dict, jnp.ndarray, int]],
                     f"active_cap={p.active_cap}")
         idx, n_spk = compact_events(spikes_src, n_rows, active_cap)
         te, we, de = _gather_entries(tables, idx)
-        e_pad = (plan[ti].entries_padded if plan is not None
-                 else _ceil_to(te.shape[0], LANES))
-        te, we, de = _pad_flat(te, we, de, e_pad)
         parts_t.append(te)
         parts_w.append(we)
         parts_d.append(de)
@@ -317,7 +378,7 @@ def event_delivery_banded(tiers: Sequence[Tuple[dict, jnp.ndarray, int]],
 # ---------------------------------------------------------------------------
 
 def synaptic_accum_pallas(idx, t_slot, tgt, w, dslot, ring, *,
-                          interpret: bool = True):
+                          interpret: bool):
     """Deliver event rows ``idx`` (A,) through the tables into ``ring``.
 
     Equivalent to ``ref.synaptic_accum_ref``.  ``dslot`` int8/int32;

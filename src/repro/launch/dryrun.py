@@ -285,8 +285,6 @@ def run_cell(arch: str, shape_name: str, mesh_kind: str,
         if shardings is not None:
             mem_d.update(analytic_memory(args, shardings, mesh))
         ca = compiled.cost_analysis() or {}
-        if isinstance(ca, list):       # older jax returns [dict]
-            ca = ca[0] if ca else {}
         hlo = compiled.as_text()
         costs = analyze_hlo(hlo)
 

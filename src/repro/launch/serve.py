@@ -377,6 +377,7 @@ def serve_sim(host: str = "127.0.0.1", port: int = 0, mesh=None,
 
 def main(argv=None):
     from repro.configs import ARCH_NAMES
+    from repro.launch.compile_cache import enable_compile_cache
 
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
     ap.add_argument("--arch", default="gemma-2b",
@@ -393,6 +394,13 @@ def main(argv=None):
     args = ap.parse_args(argv)
 
     choices = ("sim",) + tuple(ARCH_NAMES)
+    if args.arch not in choices:
+        # an unknown arch used to die with a bare KeyError from the
+        # config registry -- be explicit, and list what would work
+        raise SystemExit(
+            f"--arch {args.arch!r}: unknown arch; choices: "
+            + ", ".join(choices))
+    enable_compile_cache()
     if args.arch == "sim":
         httpd, jobs = serve_sim(args.host, args.port)
         host, port = httpd.server_address[:2]
@@ -404,12 +412,6 @@ def main(argv=None):
             httpd.shutdown()
             jobs.shutdown()
         return
-    if args.arch not in ARCH_NAMES:
-        # an unknown arch used to die with a bare KeyError from the
-        # config registry -- be explicit, and list what would work
-        raise SystemExit(
-            f"--arch {args.arch!r}: unknown arch; choices: "
-            + ", ".join(choices))
     out = serve_batch(args.arch, args.batch, args.prompt_len, args.gen)
     print(f"{out['config']}: prefill {out['prefill_s']*1e3:.1f} ms, "
           f"decode {out['decode_s_per_token']*1e3:.2f} ms/token, "

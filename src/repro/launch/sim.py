@@ -40,6 +40,7 @@ import os
 
 import numpy as np
 
+from repro.launch.compile_cache import enable_compile_cache
 from repro.obs.telemetry import (NULL, Telemetry, enable_json_logging,
                                  set_default)
 from repro.perf.trace import jax_profiler_trace, write_chrome_trace
@@ -192,6 +193,7 @@ def main(argv=None):
                          "races at their origin)")
     args = ap.parse_args(argv)
 
+    enable_compile_cache()
     if args.sanitize:
         enable_sanitizers()
     if args.log_json:

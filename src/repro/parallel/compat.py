@@ -1,11 +1,9 @@
-"""Version-compat shims for jax APIs that moved between releases.
+"""The repo's two mesh helpers, so every call site agrees on the flags.
 
-The repo targets current jax (``jax.shard_map``, ``jax.make_mesh`` with
-``axis_types``) but must also run on the 0.4.x line baked into CI/test
-containers, where ``shard_map`` still lives in ``jax.experimental`` (with
-``check_rep``/``auto`` instead of ``check_vma``/``axis_names``) and
-``jax.sharding.AxisType`` does not exist.  Every call site in the repo
-goes through these two helpers instead of hand-rolling try/except.
+``shard_map`` maps with ``check_vma=False`` (the halo permutes and the
+ensemble vmap produce values the varying-manual-axes check cannot
+type), and ``make_mesh`` builds meshes whose axes are all ``Auto``
+(jax errors on mixed implicit/explicit axis use otherwise).
 """
 
 from __future__ import annotations
@@ -13,37 +11,24 @@ from __future__ import annotations
 from typing import Optional, Set
 
 import jax
+from jax.sharding import AxisType
 
 
 def shard_map(f, mesh, in_specs, out_specs,
               axis_names: Optional[Set[str]] = None):
-    """``jax.shard_map(..., check_vma=False)`` on new jax;
-    ``jax.experimental.shard_map.shard_map(..., check_rep=False)`` on old.
+    """``jax.shard_map(..., check_vma=False)``.
 
-    ``axis_names``: mesh axes to map manually (new-API semantics); the
-    remaining axes stay under automatic propagation.  ``None`` = all.
+    ``axis_names``: mesh axes to map manually; the remaining axes stay
+    under automatic propagation.  ``None`` = all.
     """
-    if hasattr(jax, "shard_map"):
-        kwargs = {"check_vma": False}
-        if axis_names is not None:
-            kwargs["axis_names"] = set(axis_names)
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, **kwargs)
-    from jax.experimental.shard_map import shard_map as _sm
-    auto = frozenset()
+    kwargs = {"check_vma": False}
     if axis_names is not None:
-        auto = frozenset(mesh.axis_names) - frozenset(axis_names)
-    return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-               check_rep=False, auto=auto)
+        kwargs["axis_names"] = set(axis_names)
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, **kwargs)
 
 
 def make_mesh(shape, axes):
-    """``jax.make_mesh`` with Auto axis types when supported (newer jax
-    errors on mixed implicit/explicit use otherwise); plain mesh on old
-    jax, where every axis is Auto already."""
-    try:
-        from jax.sharding import AxisType
-        return jax.make_mesh(shape, axes,
-                             axis_types=(AxisType.Auto,) * len(axes))
-    except ImportError:
-        return jax.make_mesh(shape, axes)
+    """``jax.make_mesh`` with every axis ``AxisType.Auto``."""
+    return jax.make_mesh(shape, axes,
+                         axis_types=(AxisType.Auto,) * len(axes))

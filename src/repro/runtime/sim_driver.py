@@ -673,6 +673,12 @@ class SimDriver(FaultTolerantLoop):
         return (self._sim._cache_size()
                 if hasattr(self._sim, "_cache_size") else None)
 
+    def lower_segment(self, state):
+        """The segment function lowered for ``state``: what every
+        segment of ``run`` executes (``.compile().as_text()`` is its
+        HLO, e.g. to check which Pallas kernels it launches)."""
+        return self._sim.lower(state, self._sim_inputs)
+
     def run(self, n_steps: int):
         out = super().run(n_steps)
         if self.spool is not None:

@@ -284,7 +284,7 @@ def test_partial_last_block_and_lane(rng):
     ds = jnp.asarray(rng.integers(0, d_ring, (rows + 1, cap)), jnp.int8)
     ring = jnp.asarray(rng.normal(size=(d_ring, n_local)), jnp.float32)
     idx = jnp.asarray(rng.integers(0, rows + 1, 5), jnp.int32)
-    got = synaptic_accum_pallas(idx, 2, tgt, w, ds, ring)
+    got = synaptic_accum_pallas(idx, 2, tgt, w, ds, ring, interpret=True)
     want = ref.synaptic_accum_ref(idx, 2, tgt, w, ds, ring)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=1e-5, atol=1e-5)

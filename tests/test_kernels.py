@@ -69,7 +69,7 @@ def test_synaptic_accum_sweep(rows, cap, d_ring, n_local, n_events, rng):
     ds = jnp.asarray(rng.integers(0, d_ring, (rows + 1, cap)), jnp.int8)
     ring = jnp.asarray(rng.normal(size=(d_ring, n_local)), jnp.float32)
     idx = jnp.asarray(rng.integers(0, rows + 1, n_events), jnp.int32)
-    got = synaptic_accum_pallas(idx, 3, tgt, w, ds, ring)
+    got = synaptic_accum_pallas(idx, 3, tgt, w, ds, ring, interpret=True)
     want = ref.synaptic_accum_ref(idx, 3, tgt, w, ds, ring)
     np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-5)
 
@@ -81,7 +81,7 @@ def test_synaptic_accum_sink_row_is_noop(rng):
     ds = jnp.zeros((rows + 1, cap), jnp.int8)
     ring = jnp.asarray(rng.normal(size=(d_ring, n_local)), jnp.float32)
     idx = jnp.full((6,), rows, jnp.int32)        # all padding events
-    out = synaptic_accum_pallas(idx, 0, tgt, w, ds, ring)
+    out = synaptic_accum_pallas(idx, 0, tgt, w, ds, ring, interpret=True)
     np.testing.assert_array_equal(out, ring)
 
 
@@ -104,7 +104,8 @@ def test_flash_attention_sweep(bh, bhkv, sq, sk, d, causal, win, qoff, rng):
     k = jnp.asarray(rng.normal(size=(bhkv, sk, d)), jnp.float32)
     v = jnp.asarray(rng.normal(size=(bhkv, sk, d)), jnp.float32)
     got = flash_attention(q, k, v, causal=causal, window=win,
-                          q_offset=qoff, block_q=32, block_k=32)
+                          q_offset=qoff, block_q=32, block_k=32,
+                          interpret=True)
     want = ref.attention_ref(q, k, v, causal=causal, window=win,
                              q_offset=qoff)
     np.testing.assert_allclose(got, want, rtol=2e-4, atol=2e-4)
@@ -115,7 +116,8 @@ def test_flash_attention_dtypes(dtype, rng):
     q = jnp.asarray(rng.normal(size=(2, 64, 16)), dtype)
     k = jnp.asarray(rng.normal(size=(2, 64, 16)), dtype)
     v = jnp.asarray(rng.normal(size=(2, 64, 16)), dtype)
-    got = flash_attention(q, k, v, causal=True, block_q=32, block_k=32)
+    got = flash_attention(q, k, v, causal=True, block_q=32, block_k=32,
+                          interpret=True)
     want = ref.attention_ref(q, k, v, causal=True)
     assert got.dtype == dtype
     tol = 2e-4 if dtype == jnp.float32 else 2e-2
@@ -130,5 +132,5 @@ def test_flash_attention_rows_fully_masked(rng):
     v = jnp.asarray(rng.normal(size=(1, 8, 8)), jnp.float32)
     # q_offset far beyond keys: every row masked by causality+window
     out = flash_attention(q, k, v, causal=False, window=2, q_offset=100,
-                          block_q=8, block_k=8)
+                          block_q=8, block_k=8, interpret=True)
     np.testing.assert_array_equal(np.asarray(out), 0.0)

@@ -6,7 +6,6 @@ import os
 import subprocess
 import sys
 
-import jax
 import pytest
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -304,10 +303,6 @@ print("elastic OK")
 """)
 
 
-@pytest.mark.skipif(
-    not hasattr(jax, "shard_map"),
-    reason="partial-manual shard_map (auto=) hits an XLA CHECK failure "
-           "on the 0.4.x line")
 def test_compressed_pod_gradient_sync():
     """int8+error-feedback cross-pod DP: first step matches the exact
     step to int8 precision and training still converges."""

@@ -30,8 +30,6 @@ def test_analyzer_multiplies_loop_bodies():
     one_matmul = 2 * 128 ** 3
     assert costs.dot_flops == pytest.approx(10 * one_matmul, rel=0.01)
     ca = compiled.cost_analysis()
-    if isinstance(ca, list):       # older jax returns [dict]
-        ca = ca[0]
     xla_flops = ca["flops"]
     assert xla_flops == pytest.approx(one_matmul, rel=0.01)  # body once
 

@@ -27,7 +27,7 @@ from repro.core.stdp import STDPParams
 from repro.parallel.compat import make_mesh
 from repro.runtime import DriverConfig, SimDriver
 
-N = 40          # spiking sets in around step ~34 at this scale/seed
+N = 40          # spiking sets in around step ~36 at this scale/seed
 
 
 def _dist(law="gaussian", tiles=(1, 1), seed=3, stdp=STDPParams()):
@@ -131,15 +131,16 @@ def test_plastic_resume_bit_identity(tmp_path):
     """A preempted-and-resumed plastic run ends with weight tables and
     traces bit-identical to an unpreempted run: the plastic carry rides
     every checkpoint."""
-    straight = _driver(tmp_path / "a", seg=N)
-    out_a = straight.run(N)
-    assert out_a["final_step"] == N
+    steps = 60          # spiking sets in at step ~36: room for STDP to act
+    straight = _driver(tmp_path / "a", seg=steps)
+    out_a = straight.run(steps)
+    assert out_a["final_step"] == steps
 
-    first = _driver(tmp_path / "b", seg=N // 2)
-    first.run(N // 2)
-    second = _driver(tmp_path / "b", seg=N // 2)
-    out_b = second.run(N)
-    assert out_b["final_step"] == N
+    first = _driver(tmp_path / "b", seg=steps // 2)
+    first.run(steps // 2)
+    second = _driver(tmp_path / "b", seg=steps // 2)
+    out_b = second.run(steps)
+    assert out_b["final_step"] == steps
 
     for la, lb in zip(jax.tree.leaves(out_a["state"]),
                       jax.tree.leaves(out_b["state"])):

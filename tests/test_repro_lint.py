@@ -487,8 +487,7 @@ def test_pallas_geometry_flags_misalignment():
 
 
 def test_pallas_geometry_flags_vmem_blowout():
-    blown = GEOMETRY_CLEAN.replace("ENTRY_SUBLANES = 32",
-                                   "ENTRY_SUBLANES = 512")
+    blown = GEOMETRY_CLEAN.replace("TILE_N = 4096", "TILE_N = 131072")
     hits = run_checker(PallasGeometryChecker, [blown],
                        paths=["src/repro/kernels/fixture.py"])
     assert any("VMEM" in f.message for f in hits)
@@ -508,13 +507,13 @@ spec = pl.BlockSpec((ENTRY_SUBLANES, LANES), lambda i: (i, 0))
 
 
 def test_pallas_geometry_resident_ring_budget():
-    # the fused plastic step's constants (CHUNK x RING_N_MAX resident
-    # ring): clean at the shipped sizes, flagged when the ring grows
-    # past what the one-hot row factor leaves of the VMEM core
+    # the fused plastic step's constants (RING_N_MAX resident ring):
+    # clean at the shipped sizes, flagged when the ring and its per-row
+    # one-hot outgrow the VMEM core
     path = "src/repro/kernels/fixture.py"
     assert not run_checker(PallasGeometryChecker, [RING_CLEAN],
                            paths=[path])
-    blown = RING_CLEAN.replace("RING_N_MAX = 8192", "RING_N_MAX = 16384")
+    blown = RING_CLEAN.replace("RING_N_MAX = 8192", "RING_N_MAX = 131072")
     hits = run_checker(PallasGeometryChecker, [blown], paths=[path])
     assert any("RING_N_MAX" in f.message for f in hits)
 
