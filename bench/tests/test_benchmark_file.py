@@ -1,0 +1,53 @@
+"""BENCHMARK.json names only what the harness can find and run."""
+
+import json
+import re
+
+from bench import run
+
+NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.-]{0,63}$")
+UNIT = re.compile(r"^[A-Za-z0-9_/%.-]{1,16}$")
+SOURCES = {"device_trace", "program_span", "program_counter", "host_clock"}
+
+
+def bench():
+    return run.load_json(run.ROOT / "BENCHMARK.json")
+
+
+def test_every_entry_resolves():
+    b = bench()
+    assert b["command"] == ["python3", "bench/run.py"]
+    assert b["paths"] == ["bench"]
+    assert 1 <= b["run_seconds"] <= 51
+    configs = {c["name"]: c for c in b["configs"]}
+    for c in b["configs"]:
+        assert NAME.match(c["name"])
+        assert set(c) == {"name", "source", "file", "reduced", "why"}
+        assert c["file"].startswith("bench/")
+        cfg = run.load_json(run.ROOT / c["file"])
+        assert cfg["name"] == c["name"]
+        assert set(c["reduced"]) == set(cfg["reduced"])
+        assert cfg["limits"]
+    for w in b["workloads"]:
+        assert NAME.match(w["name"]) and w["chips"] in (1, 4)
+        assert set(w) == {"name", "config", "traffic", "chips", "why"}
+        assert w["config"] in configs and len(w["why"]) <= 200
+        _, cell, cfg, traffic = run.load_cell(w["name"])
+        assert cfg["chips"] == w["chips"]
+        assert traffic["warmup_steps"] % traffic["job"]["segment_steps"] == 0
+    e2e = {m["name"] for m in b["end_to_end"]}
+    assert "setup_s" in e2e
+    for m in b["end_to_end"]:
+        assert m["source"] in ("host_clock", "device_trace")
+        assert 0.01 <= m["bound"] <= 0.25
+    for kind, group in (("e2e", b["end_to_end"]), ("layer", b["per_layer"])):
+        for m in group:
+            assert NAME.match(m["name"]) and UNIT.match(m["unit"])
+            assert m["better"] in ("lower", "higher")
+            assert m["source"] in SOURCES
+            assert callable(run.reader(kind, m["name"]))
+    for m in b["per_layer"]:
+        assert m["moves"] in e2e
+        assert set(m) <= {"name", "unit", "better", "source", "layer",
+                          "moves", "workloads"}
+    assert len(json.dumps(b)) < 64 * 1024
