@@ -266,7 +266,9 @@ class AsyncCheckpointer(AsyncWriterThread):
         # per-leaf device_get is a no-op on host arrays) so transfer
         # and file write land in separate spans.
         step, tree, meta = item
-        with self.tel.span("ckpt.d2h", step=step):
+        nbytes = (sum(a.nbytes for a in jax.tree.leaves(tree))
+                  if self.tel.enabled else None)     # a span attribute
+        with self.tel.span("ckpt.d2h", step=step, bytes=nbytes):
             host = jax.device_get(tree)
         with self.tel.span("ckpt.write", step=step):
             save_checkpoint(self.directory, step, host, self.keep,

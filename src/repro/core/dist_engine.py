@@ -31,6 +31,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from ..obs.telemetry import NULL, Telemetry
 from .engine import (EngineConfig, deliver_event_tiers, external_drive,
                      init_sim_state, plastic_delivery_stdp)
 from .halo import exchange_halo_2d, pack_bits, unpack_bits
@@ -146,8 +147,9 @@ def init_dist_state(cfg: DistConfig) -> dict:
     return jax.tree.map(lambda *ls: jnp.stack(ls, axis=2), *members)
 
 
-def build_dist_tables(cfg: DistConfig,
-                      compress: bool = True) -> Tuple[SynapseTables, dict]:
+def build_dist_tables(cfg: DistConfig, compress: bool = True,
+                      telemetry: Telemetry = NULL
+                      ) -> Tuple[SynapseTables, dict]:
     """Materialize all shards' synapse tables stacked on (TY, TX).
 
     Per-shard builds happen at the analytic caps (identical shapes, so
@@ -155,21 +157,33 @@ def build_dist_tables(cfg: DistConfig,
     all-padding trailing columns jointly across shards -- the realized
     caps are cross-shard maxima, so the compressed storage descriptor
     is identical on every shard (SPMD-safe).
+
+    ``telemetry`` times each shard's host sampling and packing (with
+    its copy to the default device) as ``setup.tables.sample`` and the
+    stacking and compression as ``setup.tables.compress``.
     """
     ty, tx = cfg.tiles
     e = cfg.engine
-    tabs = [[build_tables(e.spec(), y, x, j_exc=e.lif.j_exc_mv,
-                          j_inh=e.lif.j_inh_mv, seed=e.seed)
-             for x in range(tx)] for y in range(ty)]
+
+    def sample(y, x):
+        with telemetry.span("setup.tables.sample", tile=[y, x]):
+            tab = build_tables(e.spec(), y, x, j_exc=e.lif.j_exc_mv,
+                               j_inh=e.lif.j_inh_mv, seed=e.seed)
+            telemetry.annotate(synapses=tab.stats["n_synapses"])
+        return tab
+
+    tabs = [[sample(y, x) for x in range(tx)] for y in range(ty)]
     stats = [[tabs[y][x].stats for x in range(tx)] for y in range(ty)]
 
     def stack_tree(trees):
         return jax.tree.map(lambda *ls: jnp.stack(ls), *trees)
 
-    rows = [stack_tree([tabs[y][x] for x in range(tx)]) for y in range(ty)]
-    out = stack_tree(rows)
-    if compress:
-        out = compress_tables(out)
+    with telemetry.span("setup.tables.compress"):
+        rows = [stack_tree([tabs[y][x] for x in range(tx)])
+                for y in range(ty)]
+        out = stack_tree(rows)
+        if compress:
+            out = compress_tables(out)
     from .synapses import materialized_table_bytes
     out_stats = {
         "n_synapses": int(sum(s["n_synapses"] for r in stats for s in r)),

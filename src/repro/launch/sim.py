@@ -41,8 +41,7 @@ import os
 import numpy as np
 
 from repro.launch.compile_cache import enable_compile_cache
-from repro.obs.telemetry import (NULL, Telemetry, enable_json_logging,
-                                 set_default)
+from repro.obs.telemetry import NULL, Telemetry, enable_json_logging
 from repro.perf.trace import jax_profiler_trace, write_chrome_trace
 from repro.runtime import JobError, SimDriver, SimJobSpec, build_sim_driver
 
@@ -178,9 +177,11 @@ def main(argv=None):
                          "ui.perfetto.dev; async checkpoint/spool "
                          "writer threads render as their own lanes)")
     ap.add_argument("--trace-dir", default=None,
-                    help="opt-in jax.profiler deep profile into this "
-                         "directory (XLA/device internals; heavyweight "
-                         "-- the span tracer stays cheap and separate)")
+                    help="opt-in jax.profiler deep profile of set-up "
+                         "and run into this directory (XLA/device "
+                         "internals; heavyweight); turns the span "
+                         "tracer on, so the run's spans sit in the "
+                         "profile on the device planes' clock")
     ap.add_argument("--log-json", action="store_true",
                     help="emit repro.* logs as JSON lines (one object "
                          "per record, structured event payload "
@@ -199,11 +200,10 @@ def main(argv=None):
     if args.log_json:
         enable_json_logging()
     tel = NULL
-    if args.telemetry_out or args.trace_out:
+    if args.telemetry_out or args.trace_out or args.trace_dir:
         tel = Telemetry(jsonl_path=args.telemetry_out)
-        set_default(tel)
-    driver = build_driver(args, telemetry=tel)
     with jax_profiler_trace(args.trace_dir):
+        driver = build_driver(args, telemetry=tel)
         out = driver.run(args.steps)
     t = int(np.max(np.asarray(out["state"]["t"])))
     rate = driver.firing_rate_hz(out["state"])

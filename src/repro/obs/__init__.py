@@ -19,21 +19,22 @@ distributions and slow-wave/awake activity statistics):
 A fourth layer, ``telemetry``, observes the *runtime* rather than the
 spikes: a thread-aware host-side span tracer plus a structured
 per-segment metrics stream (JSONL + Chrome-trace export via
-``repro.perf.trace``), instrumenting every driver phase -- segment
-compute, checkpoint snapshot/D2H/write, spool drain, restore/retile,
-straggler stalls.  Like recording, it is a pure observer: spike trains
-and plastic weight checksums are bit-identical with tracing on or off.
+``repro.perf.trace``), instrumenting every driver phase -- table build,
+segment compute, counter read-back, checkpoint save/snapshot/D2H/write,
+spool drain, restore/retile, straggler stalls.  Its spans are also
+profiler annotations, so a ``jax.profiler`` trace holds them on the
+device planes' clock.  Like recording, it is a pure observer: spike
+trains and plastic weight checksums are bit-identical with tracing on
+or off.
 """
 
 from .record import (RecorderSpec, init_recorder_state, record_step,
                      recorder_spec, stacked_gid_maps, tile_gid_map)
 from .spool import SpikeSpooler, load_events, read_header
-from .telemetry import (Telemetry, enable_json_logging, get_default,
-                        read_jsonl, set_default, span)
+from .telemetry import Telemetry, enable_json_logging, read_jsonl
 
 __all__ = [
     "RecorderSpec", "init_recorder_state", "record_step", "recorder_spec",
     "stacked_gid_maps", "tile_gid_map", "SpikeSpooler", "load_events",
-    "read_header", "Telemetry", "enable_json_logging", "get_default",
-    "read_jsonl", "set_default", "span",
+    "read_header", "Telemetry", "enable_json_logging", "read_jsonl",
 ]

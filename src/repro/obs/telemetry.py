@@ -5,15 +5,16 @@ wall-clock but *where the time goes*: DPSNN's companion scaling study
 (arXiv:1511.09325) decomposes time-per-simulated-second into phases
 (spike delivery, synaptic/neural dynamics, inter-process exchange) and
 shows how the exponential connectivity law shifts cost between them.
-This module is the host half of that instrument for the segmented
-driver: a low-overhead span tracer every runtime phase reports into --
-segment compute, checkpoint snapshot / D2H / file write, spool drain,
-restore and retile, straggler stalls -- plus a structured per-segment
-metrics stream.  The device half (attributing compiled-step cost to
-delivery vs neuron update vs STDP vs recorder compaction) lives in
-``benchmarks.fig_phase_breakdown``, which times each sub-function of
-the step in isolation and commits the paper-style breakdown as
-``BENCH_phase_breakdown.json``.
+This module is that instrument's host side for the segmented driver:
+a low-overhead span tracer every runtime phase reports into -- table
+build, segment compute, the per-segment counter read-back, checkpoint
+save / snapshot / D2H / file write, spool drain, restore and retile,
+straggler stalls -- plus a structured per-segment metrics stream.  An
+enabled tracer also opens each span as a ``jax.profiler.TraceAnnotation``
+on the emitting thread, so under a profiler session (``repro.launch.sim
+--trace-dir``, the benchmark's traced run) the spans sit in the same
+``.xplane.pb`` as the device planes, on one clock: a device idle gap
+reads as the program phase the host was in.
 
 Design constraints, in order:
 
@@ -22,8 +23,9 @@ Design constraints, in order:
     inside traced closures (enforced statically by repro-lint's
     ``tracer-purity`` pass, which flags a span or host clock inside a
     jit/scan body), and a disabled tracer costs one attribute check per
-    instrumentation site.  Spike trains and plastic weight checksums
-    are bit-identical with tracing on or off (tested).
+    instrumentation site: it builds no profiler annotation.  Spike
+    trains and plastic weight checksums are bit-identical with tracing
+    on or off, with or without a profiler session (tested).
   * **thread-aware** -- the async writers (``AsyncCheckpointer``,
     ``SpikeSpooler``) do their D2H transfers and file writes on daemon
     threads; their spans record the emitting thread so checkpoint wall
@@ -47,7 +49,9 @@ Record types (each one JSON dict in the JSONL stream)::
 Timestamps are seconds relative to the tracer's construction
 (``epoch_unix`` in the header anchors them to wall time).  Chrome-trace
 export (``chrome://tracing`` / Perfetto) is a view over the same
-records: ``repro.perf.trace.write_chrome_trace``.
+records: ``repro.perf.trace.write_chrome_trace``.  In the profiler
+trace a span's ``attrs`` become the annotation's stats (a list becomes
+its string), so attributes stay JSON scalars or short lists.
 """
 
 from __future__ import annotations
@@ -59,6 +63,8 @@ import os
 import threading
 import time
 from typing import Dict, List, Optional
+
+from jax.profiler import TraceAnnotation
 
 FORMAT = "dpsnn-telemetry-v1"
 
@@ -103,24 +109,28 @@ class Telemetry:
     @contextlib.contextmanager
     def span(self, name: str, **attrs):
         """Time a host-side phase.  Pure observer: the only work inside
-        the ``with`` boundary is two monotonic clock reads and (on
-        exit) one locked list append.  Never use inside jit/scan
-        closures -- the clock would read at trace time, not per step
-        (repro-lint's ``tracer-purity`` pass flags it)."""
+        the ``with`` boundary is two monotonic clock reads, a profiler
+        annotation (a no-op outside a profiler session) and, on exit,
+        one locked list append.  Never use inside jit/scan closures --
+        the clock would read at trace time, not per step (repro-lint's
+        ``tracer-purity`` pass flags it)."""
         if not self.enabled:
             yield self
             return
         stack = getattr(self._local, "stack", None)
         if stack is None:
             stack = self._local.stack = []
-        parent = stack[-1] if stack else None
-        stack.append(name)
+        parent = stack[-1][0] if stack else None
         t0 = time.perf_counter()
         try:
-            yield self
+            with TraceAnnotation(name, **attrs) as annotation:
+                stack.append((name, attrs, annotation))
+                try:
+                    yield self
+                finally:
+                    stack.pop()
         finally:
             dur = time.perf_counter() - t0
-            stack.pop()
             th = threading.current_thread()
             rec = {"type": "span", "name": name,
                    "t0": t0 - self.epoch, "dur": dur,
@@ -130,6 +140,17 @@ class Telemetry:
                 rec["attrs"] = attrs
             with self._lock:
                 self._records.append(rec)
+
+    def annotate(self, **attrs):
+        """Add attributes to this thread's innermost open span: for a
+        value the phase itself produces, such as the synapses a table
+        build sampled.  They reach the span's record and its profiler
+        annotation alike."""
+        if not self.enabled:
+            return
+        _, span_attrs, annotation = self._local.stack[-1]
+        span_attrs.update(attrs)
+        annotation.set_metadata(**attrs)
 
     # ---- structured events / metrics ----------------------------------
     def event(self, kind: str, msg: Optional[str] = None,
@@ -216,27 +237,6 @@ class Telemetry:
 #: Shared disabled tracer: the default for every instrumented component,
 #: so call sites never need a None check.
 NULL = Telemetry(enabled=False)
-
-_default: Telemetry = NULL
-
-
-def set_default(tel: Telemetry) -> Telemetry:
-    """Install the process-default tracer (used by module-level
-    ``span``); returns the previous one."""
-    global _default
-    prev, _default = _default, tel
-    return prev
-
-
-def get_default() -> Telemetry:
-    return _default
-
-
-def span(name: str, **attrs):
-    """Module-level convenience: a span on the process-default tracer.
-    Host-side only -- inside a jit/scan closure this is a trace-time
-    no-op at best and a purity violation always (lint-flagged)."""
-    return _default.span(name, **attrs)
 
 
 # ---------------------------------------------------------------------------

@@ -8,13 +8,11 @@ Thin, dependency-free views over ``repro.obs.telemetry`` records:
     lanes render *under* the main thread's segment lane and the
     double-buffered overlap is visible instead of inferred; structured
     events become instant (``"ph": "i"``) markers.
-  * **JSONL**: the raw record stream (``Telemetry.flush_jsonl`` is the
-    incremental writer; ``write_jsonl`` here is the one-shot export for
-    already-collected record lists).
   * **jax.profiler wrapper**: the opt-in deep profile
-    (``repro.launch.sim --trace-dir``) capturing XLA/TFRT internals --
-    heavyweight, so it is a separate flag from the always-cheap span
-    tracer.
+    (``repro.launch.sim --trace-dir``) capturing XLA/TFRT internals and
+    the device planes; an enabled tracer's spans land in it as profiler
+    annotations, on the same clock.  (The raw JSONL record stream is
+    ``Telemetry.flush_jsonl``.)
 """
 
 from __future__ import annotations
@@ -26,8 +24,7 @@ from typing import List, Optional
 
 from ..obs.telemetry import FORMAT, Telemetry
 
-__all__ = ["to_chrome_trace", "write_chrome_trace", "write_jsonl",
-           "jax_profiler_trace"]
+__all__ = ["to_chrome_trace", "write_chrome_trace", "jax_profiler_trace"]
 
 
 def to_chrome_trace(records: List[dict], pid: Optional[int] = None) -> dict:
@@ -84,30 +81,14 @@ def write_chrome_trace(source, path: str) -> str:
     return path
 
 
-def write_jsonl(source, path: str) -> int:
-    """One-shot JSONL export (header + every record).  For incremental
-    exactly-once appends during a run use ``Telemetry.flush_jsonl``."""
-    if isinstance(source, Telemetry):
-        return source.flush_jsonl(path)
-    d = os.path.dirname(path)
-    if d:
-        os.makedirs(d, exist_ok=True)
-    with open(path, "a") as f:
-        f.write(json.dumps({"type": "header", "format": FORMAT,
-                            "pid": os.getpid()}) + "\n")
-        for rec in source:
-            f.write(json.dumps(rec) + "\n")
-    return len(source)
-
-
 @contextlib.contextmanager
 def jax_profiler_trace(trace_dir: Optional[str]):
     """Opt-in ``jax.profiler.trace`` wrapper (``--trace-dir``).
 
     ``None`` is a no-op, so call sites wrap unconditionally.  The
     profile (TensorBoard / Perfetto protobuf under ``trace_dir``)
-    captures device/XLA internals the host-side span tracer cannot see;
-    it is heavyweight, so it stays separate from the always-cheap spans.
+    captures the device and XLA internals, and the spans of every
+    enabled ``Telemetry`` as host annotations on the same clock.
     """
     if trace_dir is None:
         yield

@@ -191,8 +191,10 @@ class FaultTolerantLoop:
             step += self.step_size
             if (step // self.step_size) % self.cfg.ckpt_every == 0 \
                     or self.preempted or step >= n_steps:
-                self._save(step, state)
-        self.ckpt.wait()
+                with self.tel.span("ckpt.save", step=step):
+                    self._save(step, state)
+        with self.tel.span("ckpt.drain", step=step):
+            self.ckpt.wait()
         return {"final_step": step, "state": state,
                 "stragglers": self.watchdog.flagged,
                 "metrics": self.metrics_log,

@@ -171,58 +171,73 @@ class SimDriver(FaultTolerantLoop):
         # every later one by global synapse id (re-sampling would build
         # a different network under the learned weights).  The birth
         # tiling rides in the checkpoint meta.
-        self._born_tiles = dist_cfg.tiles
-        if self.plastic:
-            last0 = latest_step(cfg.ckpt_dir)
-            if last0 is not None:
-                born = checkpoint_meta(cfg.ckpt_dir, last0).get("born_tiles")
-                if born:
-                    self._born_tiles = tuple(born)
-        self._birth_tables = None
-        if self.plastic and self._born_tiles != dist_cfg.tiles:
-            from ..core.synapses import materialized_table_bytes
-            born_cfg = retile_config(dist_cfg, *self._born_tiles)
-            birth, self.table_stats = build_dist_tables(born_cfg)
-            self._birth_tables = jax.tree.map(np.asarray, birth)
-            # relayed at the analytic caps, then compressed: the caps
-            # derive from the realized occupancy, which the relay
-            # preserves exactly, so any process resuming on this tiling
-            # reconstructs the identical storage descriptor
-            tables = compress_tables(retile_tables(
-                self._birth_tables, born_cfg.engine.decomp,
-                born_cfg.engine.spec(), e.decomp, e.spec()))
-            ty, tx = dist_cfg.tiles
-            self.table_stats = dict(
-                self.table_stats,
-                table_bytes_per_shard=materialized_table_bytes(
-                    tables, ty * tx))
-        else:
-            tables, self.table_stats = build_dist_tables(dist_cfg)
+        with self.tel.span("setup.tables"):
+            self._born_tiles = dist_cfg.tiles
             if self.plastic:
-                self._birth_tables = jax.tree.map(np.asarray, tables)
-        # the materialized (compressed) storage descriptor: everything
-        # that sizes shapes from the spec -- shardings, the delivery
-        # plan, plastic weight abstracts, checkpoint meta -- goes
-        # through it
-        self.storage = tables.storage
-        self._state_sh, table_sh = dist_shardings(dist_cfg, mesh,
-                                                  self.storage)
-        self._tables_host = (jax.tree.map(np.asarray, tables)
-                             if self.plastic else None)
-        if self.plastic:
-            # the plastic carry is the single live weight copy; the
-            # resident tables keep only the int8 plastic mask
-            tables = fold_plastic_tables(tables)
-        self.tables = jax.device_put(tables, table_sh)
-        self._inv_slots = None
-        if self.plastic:
-            slots, _ = build_dist_inverse_index(dist_cfg, self._tables_host)
-            self._inv_slots = jax.device_put(
-                slots, NamedSharding(mesh, dist_cfg.pspec(2)))
-            # the birth-weight stream is constant over the driver's
-            # lifetime; gather it once for plastic_summary's drift stats
-            self._birth_stream = gather_synapse_stream(
-                self._tables_host, e.decomp, e.spec())
+                last0 = latest_step(cfg.ckpt_dir)
+                if last0 is not None:
+                    born = checkpoint_meta(cfg.ckpt_dir,
+                                           last0).get("born_tiles")
+                    if born:
+                        self._born_tiles = tuple(born)
+            self._birth_tables = None
+            if self.plastic and self._born_tiles != dist_cfg.tiles:
+                from ..core.synapses import materialized_table_bytes
+                born_cfg = retile_config(dist_cfg, *self._born_tiles)
+                birth, self.table_stats = build_dist_tables(
+                    born_cfg, telemetry=telemetry)
+                with self.tel.span("setup.tables.retile"):
+                    self._birth_tables = jax.tree.map(np.asarray, birth)
+                    # relayed at the analytic caps, then compressed: the
+                    # caps derive from the realized occupancy, which the
+                    # relay preserves exactly, so any process resuming
+                    # on this tiling reconstructs the identical storage
+                    # descriptor
+                    tables = compress_tables(retile_tables(
+                        self._birth_tables, born_cfg.engine.decomp,
+                        born_cfg.engine.spec(), e.decomp, e.spec()))
+                ty, tx = dist_cfg.tiles
+                self.table_stats = dict(
+                    self.table_stats,
+                    table_bytes_per_shard=materialized_table_bytes(
+                        tables, ty * tx))
+            else:
+                tables, self.table_stats = build_dist_tables(
+                    dist_cfg, telemetry=telemetry)
+                if self.plastic:
+                    self._birth_tables = jax.tree.map(np.asarray, tables)
+            # the materialized (compressed) storage descriptor:
+            # everything that sizes shapes from the spec -- shardings,
+            # the delivery plan, plastic weight abstracts, checkpoint
+            # meta -- goes through it
+            self.storage = tables.storage
+            self._state_sh, table_sh = dist_shardings(dist_cfg, mesh,
+                                                      self.storage)
+            self._tables_host = (jax.tree.map(np.asarray, tables)
+                                 if self.plastic else None)
+            if self.plastic:
+                # the plastic carry is the single live weight copy; the
+                # resident tables keep only the int8 plastic mask
+                tables = fold_plastic_tables(tables)
+            nbytes = (sum(a.nbytes for a in jax.tree.leaves(tables))
+                      if self.tel.enabled else None)   # a span attribute
+            with self.tel.span("setup.tables.place", bytes=nbytes):
+                self.tables = jax.device_put(tables, table_sh)
+                if self.tel.enabled:
+                    # fenced only when traced, as segment.compute is
+                    jax.block_until_ready(self.tables)
+            self._inv_slots = None
+            if self.plastic:
+                with self.tel.span("setup.tables.inverse"):
+                    slots, _ = build_dist_inverse_index(dist_cfg,
+                                                        self._tables_host)
+                    self._inv_slots = jax.device_put(
+                        slots, NamedSharding(mesh, dist_cfg.pspec(2)))
+                    # the birth-weight stream is constant over the
+                    # driver's lifetime; gather it once for
+                    # plastic_summary's drift stats
+                    self._birth_stream = gather_synapse_stream(
+                        self._tables_host, e.decomp, e.spec())
 
         # cumulative totals not represented in the (possibly retiled)
         # device state -- see module docstring
@@ -270,10 +285,13 @@ class SimDriver(FaultTolerantLoop):
         self._sim = None if sim_cache is None else sim_cache.get(
             self._sim_key)
         if self._sim is None:
-            self._sim = make_sim_fn(dist_cfg, mesh, segment_steps,
-                                    record_rate=False,
-                                    recorder=self.recorder,
-                                    storage=self.storage)
+            # the delivery plan imports the kernels (Pallas) here; the
+            # trace and compile come with the first segment
+            with self.tel.span("setup.sim_fn"):
+                self._sim = make_sim_fn(dist_cfg, mesh, segment_steps,
+                                        record_rate=False,
+                                        recorder=self.recorder,
+                                        storage=self.storage)
             if sim_cache is not None:
                 sim_cache[self._sim_key] = self._sim
         self._sim_inputs = SimInputs(
@@ -493,10 +511,11 @@ class SimDriver(FaultTolerantLoop):
         if self._preempt_after is not None \
                 and self._segments_done >= self._preempt_after:
             self.preempted = True
-        totals = self.metric_totals(state)
-        prev = self._prev_totals or {k: 0.0 for k in METRIC_KEYS}
-        delta = {k: totals[k] - prev[k] for k in METRIC_KEYS}
-        self._prev_totals = totals
+        with self.tel.span("segment.metrics", step=step):
+            totals = self.metric_totals(state)
+            prev = self._prev_totals or {k: 0.0 for k in METRIC_KEYS}
+            delta = {k: totals[k] - prev[k] for k in METRIC_KEYS}
+            self._prev_totals = totals
         if delta["dropped"] > 0:
             # at most once per segment, with the segment's own delta
             # (the old run-level warning fired once and went silent

@@ -174,7 +174,7 @@ def test_sim_driver_plastic_retile_resume(tmp_path):
     """A plastic run born on 1x2 resumes on 2x1: the learned weight
     tables are relaid by global (pre, post) synapse id -- bit-identical
     per synapse (checksum) -- and the run keeps learning on the new
-    tiling."""
+    tiling.  The relay is timed as its own child of ``setup.tables``."""
     run_py(f"""
 import numpy as np
 from repro.core.connectivity import gaussian_law
@@ -182,6 +182,7 @@ from repro.core.dist_engine import DistConfig
 from repro.core.engine import EngineConfig
 from repro.core.grid import ColumnGrid, TileDecomposition
 from repro.core.stdp import STDPParams
+from repro.obs.telemetry import Telemetry
 from repro.parallel.compat import make_mesh
 from repro.runtime import DriverConfig, SimDriver
 
@@ -203,10 +204,16 @@ s1 = d1.plastic_summary(out1["state"])
 assert s1["w_l1_delta"] > 0 and s1["n_plastic"] > 0  # learning happened
 
 m21 = make_mesh((2, 1), ("data", "model"))
+tel = Telemetry()
 d2 = SimDriver(DriverConfig(ckpt_dir=ck, ckpt_every=1,
                             handle_sigterm=False),
-               dist(2, 1), m21, segment_steps=30, allow_retile=True)
+               dist(2, 1), m21, segment_steps=30, allow_retile=True,
+               telemetry=tel)
 assert d2._born_tiles == (1, 2)        # birth tiling from checkpoint meta
+kids = {{s["name"] for s in tel.spans() if s["parent"] == "setup.tables"}}
+assert kids == {{"setup.tables.sample", "setup.tables.compress",
+                "setup.tables.retile", "setup.tables.place",
+                "setup.tables.inverse"}}, kids
 start, state = d2._restore_or_init()
 assert start == 60
 s2 = d2.plastic_summary(state)

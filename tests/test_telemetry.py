@@ -1,11 +1,14 @@
 """Runtime telemetry: span tracer semantics, JSONL/Chrome round-trips,
-and the pure-observer contract on the segmented driver (spike trains
-and plastic weight checksums bit-identical with tracing on or off,
-including across preempt -> resume)."""
+the spans' profiler annotations, and the pure-observer contract on the
+segmented driver (spike trains and plastic weight checksums
+bit-identical with tracing on or off, with or without a profiler
+session, including across preempt -> resume)."""
 
+import contextlib
 import io
 import json
 import logging
+import pathlib
 import threading
 
 import jax
@@ -16,6 +19,7 @@ from repro.core.connectivity import exponential_law, gaussian_law
 from repro.core.dist_engine import DistConfig
 from repro.core.engine import EngineConfig
 from repro.core.grid import ColumnGrid, TileDecomposition
+from repro.obs import telemetry as telemetry_mod
 from repro.obs.telemetry import (FORMAT, Telemetry, enable_json_logging,
                                  read_jsonl, summarize)
 from repro.parallel.compat import make_mesh
@@ -47,6 +51,36 @@ def _driver(ckpt_dir, seg, law="gaussian", stdp=None, **kw):
 def _state_equal(a, b):
     for la, lb in zip(jax.tree.leaves(a), jax.tree.leaves(b)):
         np.testing.assert_array_equal(np.asarray(la), np.asarray(lb))
+
+
+@contextlib.contextmanager
+def _profiler(trace_dir):
+    """A CPU profiler session without the Python call tracer (the spans'
+    annotations are recorded at any host tracer level)."""
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    jax.profiler.start_trace(str(trace_dir), profiler_options=opts)
+    try:
+        yield
+    finally:
+        jax.profiler.stop_trace()
+
+
+def _profile_spans(trace_dir, names):
+    """{name: [(start_ns, duration_ns, stats)]} of the host events named
+    in ``names`` in the session's ``.xplane.pb``, in start order."""
+    path = next(pathlib.Path(trace_dir).rglob("*.xplane.pb"))
+    prof = jax.profiler.ProfileData.from_file(str(path))
+    out = {}
+    for plane in prof.planes:
+        if not plane.name.startswith("/host:"):
+            continue
+        for line in plane.lines:
+            for ev in line.events:
+                if ev.name in names:
+                    out.setdefault(ev.name, []).append(
+                        (ev.start_ns, ev.duration_ns, dict(ev.stats)))
+    return {k: sorted(v, key=lambda e: e[0]) for k, v in out.items()}
 
 
 # ---------------------------------------------------------------------------
@@ -203,15 +237,23 @@ def test_json_log_formatter_emits_structured_lines():
 # driver integration: pure observer + per-segment stream
 # ---------------------------------------------------------------------------
 
-@pytest.mark.parametrize("law", ["gaussian", "exponential"])
-def test_pure_observer_bit_identity_static(tmp_path, law):
-    """Tracing on vs off: bit-identical spike trains and final state."""
+@pytest.mark.parametrize("law,profiled", [
+    pytest.param("gaussian", False, id="gaussian"),
+    pytest.param("exponential", False, id="exponential"),
+    pytest.param("exponential", True, id="exponential-profiled"),
+])
+def test_pure_observer_bit_identity_static(tmp_path, law, profiled):
+    """Tracing on vs off: bit-identical spike trains and final state,
+    also with a profiler session recording the spans' annotations."""
     ref = _driver(tmp_path / "off", seg=10, law=law, record_events=True)
     out_ref = ref.run(N)
     tel = Telemetry()
-    traced = _driver(tmp_path / "on", seg=10, law=law,
-                     record_events=True, telemetry=tel)
-    out_tel = traced.run(N)
+    session = (_profiler(tmp_path / "prof") if profiled
+               else contextlib.nullcontext())
+    with session:
+        traced = _driver(tmp_path / "on", seg=10, law=law,
+                         record_events=True, telemetry=tel)
+        out_tel = traced.run(N)
     np.testing.assert_array_equal(ref.spike_counts(N),
                                   traced.spike_counts(N))
     _state_equal(out_ref["state"], out_tel["state"])
@@ -268,13 +310,31 @@ def test_segment_stream_carries_deltas_and_spans(tmp_path):
     # every driver phase reported spans; writer-thread work is
     # attributed to the writer threads, not the main loop
     names = {s["name"] for s in tel.spans()}
-    assert {"segment", "segment.compute", "segment.spool_drain",
-            "ckpt.snapshot", "ckpt.spool_sync", "ckpt.d2h",
-            "ckpt.write", "spool.write", "restore.init"} <= names
+    assert {"setup.tables", "setup.tables.sample", "setup.tables.compress",
+            "setup.tables.place", "setup.sim_fn", "segment",
+            "segment.compute", "segment.spool_drain", "segment.metrics",
+            "ckpt.save", "ckpt.snapshot", "ckpt.spool_sync", "ckpt.d2h",
+            "ckpt.write", "ckpt.drain", "spool.write", "restore.init"} <= names
     main_tid = tel.spans("segment")[0]["tid"]
     assert all(s["tid"] != main_tid for s in tel.spans("ckpt.write"))
     compute = tel.spans("segment.compute")[0]
     assert compute["parent"] == "segment" and compute["depth"] == 1
+    # the between-segment host path: the counter read-back inside the
+    # segment, the checkpoint hand-off after it, one of each a segment
+    assert [s["attrs"]["step"] for s in tel.spans("segment.metrics")] \
+        == [0, 10, 20, 30]
+    assert all(s["parent"] == "segment"
+               for s in tel.spans("segment.metrics"))
+    # a checkpoint's spans carry its step: where its segment ended
+    assert [s["attrs"]["step"] for s in tel.spans("ckpt.save")] \
+        == [10, 20, 30, 40]
+    assert all(s["parent"] == "ckpt.save"
+               for s in tel.spans("ckpt.snapshot") + tel.spans(
+                   "ckpt.spool_sync"))
+    d2h = tel.spans("ckpt.d2h")
+    assert [s["attrs"]["step"] for s in d2h] == [10, 20, 30, 40]
+    state_bytes = sum(a.nbytes for a in jax.tree.leaves(out["state"]))
+    assert all(s["attrs"]["bytes"] == state_bytes for s in d2h)
 
 
 def test_analyze_cli_folds_in_telemetry_summary(tmp_path):
@@ -320,3 +380,106 @@ def test_exactly_once_stream_across_preempt_resume(tmp_path):
     assert sorted(segs) == [0, 10, 20, 30]
     resumes = [e for e in back["event"] if e["kind"] == "resume"]
     assert len(resumes) == 1 and resumes[0]["step"] == 10
+
+
+# ---------------------------------------------------------------------------
+# one timeline: spans in the profiler trace, set-up spans
+# ---------------------------------------------------------------------------
+
+def test_spans_land_in_the_profiler_trace(tmp_path):
+    """Under a profiler session every span is also a host annotation
+    with its attributes as stats, timed as the tracer timed it."""
+    tel = Telemetry()
+    with _profiler(tmp_path / "prof"):
+        drv = _driver(tmp_path / "ckpt", seg=10, telemetry=tel)
+        drv.run(20)
+    names = {"setup.tables", "setup.tables.sample", "setup.tables.compress",
+             "setup.tables.place", "segment.compute", "segment.metrics",
+             "ckpt.save", "ckpt.d2h"}
+    got = _profile_spans(tmp_path / "prof", names)
+    assert set(got) == names
+    for name in names:
+        mine = sorted(tel.spans(name), key=lambda s: s["t0"])
+        theirs = got[name]
+        assert len(theirs) == len(mine), name
+        for rec, (_, dur_ns, stats) in zip(mine, theirs):
+            assert abs(dur_ns * 1e-9 - rec["dur"]) < 1e-3, name
+            for k, v in rec.get("attrs", {}).items():
+                # a list attribute becomes its string
+                assert stats[k] == (str(v) if isinstance(v, list) else v)
+    assert [s["step"] for _, _, s in got["segment.metrics"]] == [0, 10]
+    assert [s["step"] for _, _, s in got["ckpt.d2h"]] == [10, 20]
+    assert got["setup.tables.sample"][0][2]["tile"] == "[0, 0]"
+
+
+def test_disabled_tracer_builds_no_annotation(tmp_path, monkeypatch):
+    def refuse(*args, **kwargs):
+        raise AssertionError("a profiler annotation was built")
+
+    monkeypatch.setattr(telemetry_mod, "TraceAnnotation", refuse)
+    drv = _driver(tmp_path, seg=10)              # the default NULL tracer
+    assert drv.run(20)["final_step"] == 20
+    Telemetry(enabled=False).annotate(synapses=1)
+    # an enabled tracer does go through the patched name
+    with pytest.raises(AssertionError, match="annotation was built"):
+        with Telemetry().span("segment"):
+            pass
+
+
+@pytest.mark.parametrize("plastic", [False, True])
+def test_setup_tables_spans_enclose_the_build(tmp_path, plastic):
+    from repro.core.stdp import STDPParams
+    tel = Telemetry()
+    drv = _driver(tmp_path, seg=10, stdp=STDPParams() if plastic else None,
+                  telemetry=tel)
+    outer, = tel.spans("setup.tables")
+    kids = [s for s in tel.spans() if s["name"].startswith("setup.tables.")]
+    want = {"setup.tables.sample", "setup.tables.compress",
+            "setup.tables.place"} | ({"setup.tables.inverse"}
+                                     if plastic else set())
+    assert {k["name"] for k in kids} == want
+    for k in kids:
+        assert k["parent"] == "setup.tables"
+        assert k["depth"] == outer["depth"] + 1
+        assert outer["t0"] <= k["t0"]
+        assert k["t0"] + k["dur"] <= outer["t0"] + outer["dur"]
+    assert sum(s["attrs"]["synapses"]
+               for s in tel.spans("setup.tables.sample")) \
+        == drv.table_stats["n_synapses"]
+    place, = tel.spans("setup.tables.place")
+    assert place["attrs"]["bytes"] == sum(
+        a.nbytes for a in jax.tree.leaves(drv.tables))
+
+
+def test_table_sample_spans_one_per_shard():
+    from repro.core.dist_engine import build_dist_tables
+    lw = gaussian_law()
+    dec = TileDecomposition(grid=ColumnGrid(4, 4, 10), tiles_y=2,
+                            tiles_x=2, radius=lw.radius)
+    cfg = DistConfig(engine=EngineConfig(decomp=dec, law=lw, seed=3))
+    tel = Telemetry()
+    _, stats = build_dist_tables(cfg, telemetry=tel)
+    sample = tel.spans("setup.tables.sample")
+    assert [s["attrs"]["tile"] for s in sample] \
+        == [[0, 0], [0, 1], [1, 0], [1, 1]]
+    assert all(s["attrs"]["synapses"] > 0 for s in sample)
+    assert sum(s["attrs"]["synapses"] for s in sample) \
+        == stats["n_synapses"]
+    assert len(tel.spans("setup.tables.compress")) == 1
+
+
+def test_sim_cli_trace_dir_profiles_set_up_and_spans(tmp_path,
+                                                   monkeypatch):
+    """``--trace-dir`` alone turns the span tracer on and opens the
+    profile before the driver is built, so set-up spans land in it."""
+    from repro.launch import sim
+    # the CLI's persistent compile cache would outlive this test
+    monkeypatch.setattr(sim, "enable_compile_cache", lambda: None)
+    sim.main(["--grid", "4", "--neurons-per-column", "10", "--steps", "20",
+              "--segment-steps", "10", "--ckpt-dir", str(tmp_path / "ckpt"),
+              "--trace-dir", str(tmp_path / "prof")])
+    got = _profile_spans(tmp_path / "prof", {"setup.tables.sample",
+                                             "segment.compute", "ckpt.drain"})
+    assert len(got["setup.tables.sample"]) == 1
+    assert [s["step"] for _, _, s in got["segment.compute"]] == [0, 10]
+    assert len(got["ckpt.drain"]) == 1
