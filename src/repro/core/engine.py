@@ -46,9 +46,10 @@ class EngineConfig:
     cap_headroom: float = 8.0        # event-list sizing (perf knob)
     seed: int = 0
     # Weight *storage* dtype.  bfloat16 by default: sampled weights are
-    # quantized to this dtype at build time (realization v3) and cast
-    # up to float32 before any delivery arithmetic, so event delivery
-    # is bit-identical to a float32 copy of the same tables while the
+    # quantized to this dtype at build time (realization v3) and every
+    # delivery sum accumulates in float32 (the kernels contract the
+    # bfloat16 stream in one exact MXU pass), so event delivery is
+    # bit-identical to a float32 copy of the same tables while the
     # weight array -- the largest memory term after target ids --
     # halves.  Plastic runs override to float32 (see ``spec()``).
     weight_dtype: str = "bfloat16"
@@ -81,6 +82,16 @@ class EngineConfig:
                     "or a bool")
             return True
         return bool(self.use_kernels)
+
+    def delivery_w_dtype(self, weight_dtype: str) -> str:
+        """The dtype event delivery contracts weights stored as
+        ``weight_dtype`` in: the kernels keep bfloat16 (one MXU pass a
+        lane row) and widen the rest to float32; the XLA path widens
+        every weight."""
+        if self.mode == "event" and self.kernels_enabled:
+            from ..kernels.synaptic_accum import weight_stream_dtype
+            return weight_stream_dtype(weight_dtype).name
+        return "float32"
 
     def spec(self) -> SynapseTableSpec:
         single = self.decomp.tiles_y == 1 and self.decomp.tiles_x == 1

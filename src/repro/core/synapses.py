@@ -79,8 +79,9 @@ class TableStorage:
     They start at the spec's analytic caps and shrink when
     ``compress_tables`` truncates all-padding trailing columns (bucketed
     row storage for bands whose realized nnz is far below the analytic
-    cap).  ``accum_dtype`` is the dtype delivery accumulates in; weights
-    are cast up from ``weight_dtype`` before any arithmetic, which keeps
+    cap).  ``accum_dtype`` is the dtype delivery accumulates in; every
+    product of a weight is exact in it (the kernels contract bfloat16
+    weights in one MXU pass, the XLA path casts them up), which keeps
     delivery bit-identical across storage formats because sampled
     weights are quantized to ``weight_dtype`` at build time.
     """

@@ -54,8 +54,17 @@ together themselves.  The TPU shape of the problem:
     dimension innermost: each ring tile stays VMEM-resident while every
     entry block streams past it.
 
-The contraction runs at ``Precision.HIGHEST``: plastic weights are
-float32, and the MXU's default single bf16 pass would round them.
+The contraction's pass count follows the weight stream's dtype, which
+is the tables' storage dtype (``weight_stream_dtype``).  A bfloat16
+stream (static tables, quantized at build time) builds both one-hot
+factors in bfloat16 and contracts them in ONE default-precision MXU
+pass into a float32 accumulator: a one-hot factor holds only 0 and 1,
+so every product is an exact bfloat16 weight, and the result is bit for
+bit what ``Precision.HIGHEST`` gives on a float32 copy.  A float32
+stream (plastic weights, float32 tables, and ``spike_compact``'s
+neuron indices) keeps ``Precision.HIGHEST``: one bfloat16 pass would
+round it.
+
 Interpret mode (CPU) executes the identical BlockSpec tiling and kernel
 body with jnp ops, so tests exercise the same code path that compiles
 on TPU; ``tests/test_tpu_compile.py`` compiles it for a described v5e.
@@ -77,8 +86,9 @@ TILE_N = 4096             # max ring-tile width (lane dim, multiple of 128)
 N_ALIGN = 8 * LANES       # ring width alignment: whole (8, 128) tiles
 
 # Per sublane row the kernel holds a (d_ring * TILE_N / LANES, LANES)
-# row one-hot (128 KiB f32 at d_ring=8) and a (LANES, LANES) lane
-# factor; the ring tile in and out is 2 x 128 KiB.
+# row one-hot and a (LANES, LANES) lane factor, both in the weight
+# stream's dtype: 64 + 32 KiB for bfloat16, 128 + 64 KiB for float32 at
+# d_ring=8.  The float32 ring tile in and out is 2 x 128 KiB.
 
 _FAR = 2 ** 30            # min-reduction sentinel for non-live entries
 _SUB = 8                  # sublanes per vreg: rows loaded at a time
@@ -96,6 +106,15 @@ def packed_total(entries: int) -> int:
     return _ceil_to(max(entries, ENTRY_BLOCK), ENTRY_BLOCK)
 
 
+def weight_stream_dtype(w_dtype) -> jnp.dtype:
+    """The dtype delivery carries weights stored as ``w_dtype`` in:
+    bfloat16 stays bfloat16 (one MXU pass per lane row), anything else
+    is widened to float32 (``Precision.HIGHEST``)."""
+    if jnp.dtype(w_dtype) == jnp.bfloat16:
+        return jnp.dtype(jnp.bfloat16)
+    return jnp.dtype(jnp.float32)
+
+
 def row_contrib(rid, lo, w, n_rows: int):
     """One-hot contraction of one lane-packed entry row.
 
@@ -103,16 +122,20 @@ def row_contrib(rid, lo, w, n_rows: int):
     and weight.  Returns the (n_rows, LANES) f32 contribution
     ``out[r, b] = sum_e w[e] * [rid[e] == r] * [lo[e] == b]``: the row
     one-hot and the weighted lane one-hot both keep the entries on
-    lanes, and the matmul contracts those lanes.
+    lanes, and the matmul contracts those lanes.  Both factors take
+    ``w``'s dtype: bfloat16 contracts in one exact MXU pass, float32 at
+    ``Precision.HIGHEST``.
     """
     oh_row = (rid == jax.lax.broadcasted_iota(
-        jnp.int32, (n_rows, LANES), 0)).astype(jnp.float32)
+        jnp.int32, (n_rows, LANES), 0)).astype(w.dtype)
     lane_w = jnp.where(lo == jax.lax.broadcasted_iota(
-        jnp.int32, (LANES, LANES), 0), w, 0.0)
+        jnp.int32, (LANES, LANES), 0), w.astype(jnp.float32),
+        0.0).astype(w.dtype)
+    precision = (jax.lax.Precision.DEFAULT if w.dtype == jnp.bfloat16
+                 else jax.lax.Precision.HIGHEST)
     return jax.lax.dot_general(
         oh_row, lane_w, dimension_numbers=(((1,), (1,)), ((), ())),
-        precision=jax.lax.Precision.HIGHEST,
-        preferred_element_type=jnp.float32)
+        precision=precision, preferred_element_type=jnp.float32)
 
 
 def split_target(tgt):
@@ -128,6 +151,7 @@ def scatter_rows(tgt_ref, w_ref, d_ref, row0: int, n_rows: int, t0,
 
     Returns the (d_ring * n_hi, LANES) f32 sum, row ``d * n_hi + a``
     holding slot ``d``, neurons ``base + a * LANES + [0, LANES)``.
+    ``w_ref``'s dtype picks the contraction (``row_contrib``).
     Rows load ``_SUB`` at a time; the sum runs over the rows in order,
     so every kernel that calls this reduces a block identically.
     Entries outside the tile are zeroed through ``w``: their fused row
@@ -141,7 +165,7 @@ def scatter_rows(tgt_ref, w_ref, d_ref, row0: int, n_rows: int, t0,
     def group(g, acc):
         r0 = pl.multiple_of(row0 + g * _SUB, _SUB)
         tgt = tgt_ref[pl.ds(r0, _SUB), :]
-        w = w_ref[pl.ds(r0, _SUB), :].astype(jnp.float32)
+        w = w_ref[pl.ds(r0, _SUB), :]
         slots = (t0 + d_ref[pl.ds(r0, _SUB), :]) % d_ring
         local = tgt - base
         w = jnp.where(jnp.logical_and(local >= 0, local < n_hi * LANES),
@@ -209,7 +233,8 @@ def _scatter_entries(tgt_e, w_e, d_e, ring, t_slot, *,
     tiled ring.
 
     tgt_e/w_e/d_e: flat (E,) with E a multiple of ENTRY_BLOCK; padding
-    entries must carry w == 0.  One pallas_call covers every
+    entries must carry w == 0.  ``w_e`` stays in its dtype
+    (``weight_stream_dtype``).  One pallas_call covers every
     (ring tile, entry block) pair on a 2-D grid.
     """
     d_ring, n_local = ring.shape
@@ -243,7 +268,7 @@ def _scatter_entries(tgt_e, w_e, d_e, ring, t_slot, *,
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
         name="synaptic_accum",
-    )(meta, tmin, tmax, packed(tgt_e, jnp.int32), packed(w_e, jnp.float32),
+    )(meta, tmin, tmax, packed(tgt_e, jnp.int32), w_e.reshape(-1, LANES),
       packed(d_e, jnp.int32), ring_p)
     return out.reshape(d_ring, n_pad)[:, :n_local]
 
@@ -288,9 +313,11 @@ def gather_rows(table, idx, dtype):
 
 
 def _gather_entries(tables: dict, idx):
-    """Gather event rows into lane-padded (tgt, w, dslot) streams."""
+    """Gather event rows into lane-padded (tgt, w, dslot) streams; the
+    weights stay in their ``weight_stream_dtype``."""
+    w = tables["w"]
     return (gather_rows(tables["tgt"], idx, jnp.int32),
-            gather_rows(tables["w"], idx, jnp.float32),
+            gather_rows(w, idx, weight_stream_dtype(w.dtype)),
             gather_rows(tables["dslot"], idx, jnp.int32))
 
 

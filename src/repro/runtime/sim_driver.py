@@ -288,6 +288,8 @@ class SimDriver(FaultTolerantLoop):
             # the delivery plan imports the kernels (Pallas) here; the
             # trace and compile come with the first segment
             with self.tel.span("setup.sim_fn"):
+                self.tel.annotate(delivery_w_dtype=e.delivery_w_dtype(
+                    self.storage.weight_dtype))
                 self._sim = make_sim_fn(dist_cfg, mesh, segment_steps,
                                         record_rate=False,
                                         recorder=self.recorder,

@@ -15,7 +15,7 @@ from repro.core.engine import (EngineConfig, build_shard_tables,
 from repro.core.grid import ColumnGrid, TileDecomposition
 from repro.core.synapses import SynapseTableSpec, build_tables, deliver_events
 from repro.kernels import ref
-from repro.kernels.synaptic_accum import (ENTRY_BLOCK, LANES,
+from repro.kernels.synaptic_accum import (ENTRY_BLOCK, LANES, TILE_N,
                                           compact_events, event_delivery,
                                           event_delivery_banded,
                                           synaptic_accum_pallas)
@@ -288,3 +288,51 @@ def test_partial_last_block_and_lane(rng):
     want = ref.synaptic_accum_ref(idx, 2, tgt, w, ds, ring)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=1e-5, atol=1e-5)
+
+
+# ---------------------------------------------------------------------------
+# Weight-stream dtype: one bfloat16 MXU pass vs float32 at HIGHEST
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("law_name", ["gaussian", "exponential"])
+def test_bf16_stream_matches_float32_copy_bitwise(law_name, rng):
+    """Banded delivery over bfloat16 tables (one bfloat16 pass a lane
+    row) is bit-identical to the same call over a float32 copy of the
+    tables (``Precision.HIGHEST``).  Entry 1 of every row repeats entry
+    0's target and delay (duplicate targets within a lane row), and the
+    ring spans two ring tiles with every other entry moved one tile up
+    at the same in-tile offset, so each tile sees out-of-tile entries
+    whose fused row id aliases a live one."""
+    law = gaussian_law() if law_name == "gaussian" else exponential_law()
+    spec = dataclasses.replace(
+        _dist_spec(law, n_per_col=20 if law_name == "gaussian" else 12),
+        weight_dtype="bfloat16")
+    tabs = build_tables(spec, 1, 1, j_exc=0.4, j_inh=-2.0, seed=3)
+    n_wide = TILE_N + spec.n_local
+
+    def remap(tab):
+        tgt = np.asarray(tab["tgt"]).astype(np.int32)
+        ds = np.asarray(tab["dslot"]).copy()
+        tgt[:, 1], ds[:, 1] = tgt[:, 0], ds[:, 0]
+        tgt[:, 2::2] += TILE_N
+        return dict(tab, tgt=jnp.asarray(tgt), dslot=jnp.asarray(ds))
+
+    bf16 = [remap(t) for t in [tabs["local"]] + list(tabs["halo"])]
+    assert all(t["w"].dtype == jnp.bfloat16 for t in bf16)
+    f32 = [dict(t, w=t["w"].astype(jnp.float32)) for t in bf16]
+    caps = [spec.active_cap_local] + [spec.active_cap_band(b)
+                                      for b in spec.halo_bands()]
+    spikes = [jnp.asarray((rng.random(spec.n_local) < 0.08)
+                          .astype(np.float32))] + _band_spikes(spec, rng)
+    ring0 = jnp.asarray(rng.normal(size=(spec.d_ring, n_wide)), jnp.float32)
+
+    def deliver(tables):
+        tiers = list(zip(tables, spikes, caps))
+        return jax.jit(lambda r: event_delivery_banded(
+            tiers, r, 5, spec.d_ring, interpret=True))(ring0)
+
+    ring_b, ev_b, _ = deliver(bf16)
+    ring_f, ev_f, _ = deliver(f32)
+    np.testing.assert_array_equal(np.asarray(ring_b), np.asarray(ring_f))
+    assert int(ev_b) == int(ev_f) > 0
+    assert not np.array_equal(np.asarray(ring_b), np.asarray(ring0))

@@ -1,5 +1,6 @@
 """Pallas kernels vs pure-jnp oracles: shape/dtype sweeps + properties."""
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -11,7 +12,8 @@ except ImportError:  # hermetic env: see tests/_hypothesis_fallback.py
 from repro.core.neuron import LIFParams
 from repro.kernels import ops, ref
 from repro.kernels.flash_attention import flash_attention
-from repro.kernels.synaptic_accum import synaptic_accum_pallas
+from repro.kernels.synaptic_accum import (LANES, row_contrib,
+                                          synaptic_accum_pallas)
 
 
 # ---------------------------------------------------------------------------
@@ -83,6 +85,36 @@ def test_synaptic_accum_sink_row_is_noop(rng):
     idx = jnp.full((6,), rows, jnp.int32)        # all padding events
     out = synaptic_accum_pallas(idx, 0, tgt, w, ds, ring, interpret=True)
     np.testing.assert_array_equal(out, ring)
+
+
+def _dot_precisions(fn, *args):
+    eqns = jax.make_jaxpr(fn)(*args).jaxpr.eqns
+    return [(e.params["precision"], [v.aval.dtype for v in e.invars])
+            for e in eqns if e.primitive.name == "dot_general"]
+
+
+@pytest.mark.parametrize("w_dtype", ["float32", "bfloat16"])
+def test_row_contrib_pass_count_follows_weight_dtype(w_dtype):
+    """float32 weights (plastic tables, spike_compact's indices) keep
+    ``Precision.HIGHEST`` and their exact float32 sum; bfloat16 weights
+    take one default-precision pass on bfloat16 factors."""
+    # every entry on ring row 3, lane 5: a weight 1 + 2**-12 is not a
+    # bfloat16, and 128 of them sum exactly to 128 + 2**-5 in float32
+    rid = jnp.full((1, LANES), 3, jnp.int32)
+    lo = jnp.full((1, LANES), 5, jnp.int32)
+    val = 1.0 + 2.0 ** -12 if w_dtype == "float32" else 1.0 + 2.0 ** -7
+    w = jnp.full((1, LANES), val, w_dtype)
+    out = np.asarray(jax.jit(row_contrib, static_argnums=3)(rid, lo, w, 8))
+    assert out.dtype == np.float32
+    want = np.zeros((8, LANES), np.float32)
+    want[3, 5] = np.float32(LANES * val)
+    np.testing.assert_array_equal(out, want)
+    (prec, dts), = _dot_precisions(lambda a, b, c: row_contrib(a, b, c, 8),
+                                   rid, lo, w)
+    want_prec = (jax.lax.Precision.HIGHEST if w_dtype == "float32"
+                 else jax.lax.Precision.DEFAULT)
+    assert prec == (want_prec, want_prec)
+    assert dts == [jnp.dtype(w_dtype)] * 2
 
 
 # ---------------------------------------------------------------------------

@@ -451,6 +451,21 @@ def test_setup_tables_spans_enclose_the_build(tmp_path, plastic):
         a.nbytes for a in jax.tree.leaves(drv.tables))
 
 
+@pytest.mark.parametrize("plastic", [False, True])
+def test_sim_fn_span_names_the_delivery_weight_dtype(tmp_path, plastic):
+    """Static tables (bfloat16 storage) deliver a bfloat16 weight stream
+    in one MXU pass a lane row; plastic runs carry float32 weights and
+    keep ``Precision.HIGHEST``.  The set-up span says which."""
+    from repro.core.stdp import STDPParams
+    tel = Telemetry()
+    drv = _driver(tmp_path, seg=10, stdp=STDPParams() if plastic else None,
+                  telemetry=tel)
+    span, = tel.spans("setup.sim_fn")
+    want = "float32" if plastic else "bfloat16"
+    assert drv.storage.weight_dtype == want
+    assert span["attrs"]["delivery_w_dtype"] == want
+
+
 def test_table_sample_spans_one_per_shard():
     from repro.core.dist_engine import build_dist_tables
     lw = gaussian_law()

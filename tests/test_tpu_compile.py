@@ -16,6 +16,7 @@ process at a time may load the TPU library, and the test workers import
 every test file.
 """
 
+import dataclasses
 import os
 
 import jax
@@ -71,14 +72,18 @@ def _on(sharding, tree):
         tree)
 
 
+@pytest.mark.parametrize("w_dtype", ["bfloat16", "float32"])
 @pytest.mark.parametrize("law,tiles", [("gaussian", (1, 1)),
                                        ("exponential", (2, 2))])
-def test_banded_delivery_compiles(one_chip, law, tiles):
-    spec = dist_config_for(
+def test_banded_delivery_compiles(one_chip, law, tiles, w_dtype):
+    """Both contractions: the one-pass bfloat16 one (static storage) and
+    the float32 one at ``Precision.HIGHEST``."""
+    spec = dataclasses.replace(dist_config_for(
         SimJobSpec(ckpt_dir="unused", grid=GRID, n_per_column=NPC, law=law),
-        tiles).engine.spec()
+        tiles).engine.spec(), weight_dtype=w_dtype)
     plan = spec.delivery_plan()
     tables = _on(one_chip, spec.abstract_tables().tiers())
+    assert all(t["w"].dtype == jnp.dtype(w_dtype) for t in tables)
     spikes = [jax.ShapeDtypeStruct((p.rows,), jnp.float32,
                                    sharding=one_chip) for p in plan]
     ring = jax.ShapeDtypeStruct((8, spec.n_local), jnp.float32,
