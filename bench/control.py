@@ -28,11 +28,13 @@ sys.path.insert(0, str(Path(__file__).resolve().parents[1]))
 from bench import reference, run  # noqa: E402
 
 
-def control_readings(config: dict, seg: int, inputs) -> dict:
-    """The control's readings on the sound run's checkpointed starts."""
+def control_readings(config: dict, seg: int, inputs, devices) -> dict:
+    """The control's readings on the sound run's checkpointed starts,
+    its blocks on ``devices`` as the reference's."""
     import numpy as np
     model = reference.Model.from_config(config)
-    syn = reference.device_synapses(model, reference.connectome(model))
+    syn = reference.device_synapses(model, reference.connectome(model),
+                                    devices)
     low = reference.make_segment(model, seg, "bfloat16")
     outs = []
     for start, _, _ in inputs:
@@ -41,7 +43,7 @@ def control_readings(config: dict, seg: int, inputs) -> dict:
                      {"spikes": float(spikes.sum()),
                       "events": float(events.sum())}))
     del syn
-    return run.compare(config, seg, outs)
+    return run.compare(config, seg, outs, devices)
 
 
 def main(argv=None) -> int:
@@ -53,13 +55,16 @@ def main(argv=None) -> int:
     _, cell, config, traffic = run.load_cell(args.workload)
     peaks = run.load_json(run.BENCH / "peaks.json")
     seg = traffic["job"]["segment_steps"]
+    import jax
+    devices = jax.devices()[:cell["chips"]]
     for seed in args.seeds:
         ctx = run.run_cell(cell, config, traffic, seed, args.seconds, False,
                            peaks, t_start=time.perf_counter())
         print(json.dumps({
             "workload": args.workload, "seed": seed,
             "sound": ctx["readings"],
-            "control": control_readings(config, seg, ctx["check_inputs"]),
+            "control": control_readings(config, seg, ctx["check_inputs"],
+                                        devices),
         }), flush=True)
     return 0
 

@@ -1,5 +1,6 @@
 """Share of the window (%) outside the driver's ``segment.compute``
-spans: metric pulls, checkpoint snapshots, host bookkeeping."""
+spans: metric pulls, checkpoint snapshots, host bookkeeping.  One
+driver thread serves all chips: no division by chips."""
 
 
 def read(ctx):

@@ -1,5 +1,6 @@
 """Device milliseconds of the ``synaptic_accum`` delivery kernel per
-simulated step, from the profiler trace of the window."""
+simulated step on one chip: its time in the profiler trace of the
+window, averaged over the chips, over the window's steps."""
 
 KERNEL = "synaptic_accum"
 

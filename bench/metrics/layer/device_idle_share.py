@@ -1,5 +1,5 @@
-"""Share (%) of the traced window in which no operation ran on the
-device (one minus the busy union over the window)."""
+"""Share (%) of the traced window in which no operation ran on a chip:
+one minus the busy union over the window, averaged over the chips."""
 
 
 def read(ctx):

@@ -1,6 +1,7 @@
 """Share (%) of the window's device-idle time that no program span
 explains: the main thread had no span open, or only the outer
-``segment`` span (``bench/spans.py``)."""
+``segment`` span (``bench/spans.py``); both idle times are averaged
+over the chips."""
 
 
 def read(ctx):

@@ -1,6 +1,6 @@
 """Host milliseconds per window segment spent between segments on the
 driver's thread: the counter read-back (``segment.metrics``) and the
-checkpoint hand-off (``ckpt.save``)."""
+checkpoint hand-off (``ckpt.save``), for all chips at once."""
 
 NAMES = ("segment.metrics", "ckpt.save")
 

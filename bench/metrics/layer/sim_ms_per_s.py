@@ -1,4 +1,5 @@
-"""Simulated milliseconds per wall second in the window."""
+"""Simulated milliseconds of the whole grid per wall second in the
+window (all chips step together: no division by chips)."""
 
 
 def read(ctx):

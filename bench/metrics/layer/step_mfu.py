@@ -1,6 +1,7 @@
 """Share (%) of the chips' peak FLOP/s that the window's required step
-work (``bench/work.py``: delivery plus the neuron update) reaches over
-the window's wall time."""
+work (``bench/work.py``: delivery plus the neuron update of the whole
+grid) reaches over the window's wall time: divided by the window times
+the cell's chips times one chip's peak."""
 
 
 def read(ctx):

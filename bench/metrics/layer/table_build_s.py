@@ -1,5 +1,5 @@
 """Seconds to build the driver: the host synapse-table build and
-placing the tables on the device (``build_sim_driver``)."""
+placing the tables on the cell's chips (``build_sim_driver``)."""
 
 
 def read(ctx):

@@ -1,5 +1,6 @@
 """Bytes of the materialized synapse tables per synapse, as the
-simulator counts them (``table_bytes_per_shard`` over all shards)."""
+simulator counts them: ``table_bytes_per_shard`` times the cell's
+chips (one shard each) over the configuration's synapses."""
 
 
 def read(ctx):

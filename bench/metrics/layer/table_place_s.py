@@ -1,6 +1,6 @@
 """Seconds of moving the sampled tables into place in set-up: stacking
 and compressing them (``setup.tables.compress``) and putting them on
-the mesh (``setup.tables.place``)."""
+the mesh (``setup.tables.place``): host seconds for all chips."""
 
 NAMES = ("setup.tables.compress", "setup.tables.place")
 

@@ -1,6 +1,7 @@
 """Seconds of host synapse sampling and row packing in set-up: the
-``setup.tables.sample`` spans, one per shard, each with its copy of
-the shard's tables to the default device."""
+sum of the ``setup.tables.sample`` spans, one per shard (one per chip,
+sampled one after another), each with its copy of the shard's tables
+to the default device."""
 
 
 def read(ctx):

@@ -61,7 +61,7 @@ for p in (str(ROOT), str(ROOT / "src")):
     if p not in sys.path:
         sys.path.insert(0, p)
 
-from bench import reference, trace as trace_reduce, work  # noqa: E402
+from bench import reference, spans, trace as trace_reduce, work  # noqa: E402
 
 COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
 CACHE_HIT = "/jax/compilation_cache/cache_hits"
@@ -203,6 +203,7 @@ def stated_vs_run(config: dict, driver) -> Dict[str, tuple]:
         "law": e.law.kind, "amplitude": e.law.amplitude,
         "scale_um": e.law.scale_um, "cutoff": e.law.cutoff,
         "alpha_um": e.law.alpha_um, "frac_exc": spec.frac_exc,
+        "halo_floor": spec.halo_floor,
         "p_local": spec.p_local, "j_exc": e.lif.j_exc_mv,
         "j_inh": e.lif.j_inh_mv,
         "w_jitter": inspect.signature(build_tables)
@@ -250,14 +251,24 @@ def checkpoint_steps(ckpt_dir: str) -> List[int]:
                   if n.startswith("step_") and "tmp" not in n)
 
 
-def flat_state(tree: dict) -> dict:
-    """The simulator's one-tile state in the reference's terms."""
+def flat_state(tree: dict, model: reference.Model) -> dict:
+    """The simulator's state, stacked over tiles ``[ty, tx, ...]``, in
+    the reference's terms: every neuron in global order, the neurons of
+    padded columns dropped, one key per tile."""
     import numpy as np
-    return {"v": np.asarray(tree["neuron"]["v"])[0, 0],
-            "c": np.asarray(tree["neuron"]["c"])[0, 0],
-            "refrac": np.asarray(tree["neuron"]["refrac"])[0, 0],
-            "ring": np.asarray(tree["i_ring"])[0, 0],
-            "key": np.asarray(tree["rng"])[0, 0],
+    order = model.slot_of_neuron()
+
+    def neurons(a):
+        a = np.asarray(a)
+        lead = a.shape[2:-1]
+        a = np.moveaxis(a.reshape((-1,) + a.shape[2:]), 0, -2)
+        return a.reshape(lead + (-1,))[..., order]
+
+    return {"v": neurons(tree["neuron"]["v"]),
+            "c": neurons(tree["neuron"]["c"]),
+            "refrac": neurons(tree["neuron"]["refrac"]),
+            "ring": neurons(tree["i_ring"]),
+            "key": np.asarray(tree["rng"]),
             "t": np.asarray(tree["t"])[0, 0]}
 
 
@@ -274,6 +285,10 @@ def run_cell(cell: dict, config: dict, traffic: dict, seed: int,
     from repro.obs.telemetry import NULL, Telemetry
     from repro.runtime import build_sim_driver
 
+    tiles = config["tiles"]
+    if tiles[0] * tiles[1] != cell["chips"]:
+        raise Mismatch(f"tiles {tiles} need {tiles[0] * tiles[1]} chips, "
+                       f"the cell has {cell['chips']}")
     job = traffic["job"]
     if job.get("plastic") or job.get("seeds"):
         raise Mismatch("the reference simulates one static network; this "
@@ -336,8 +351,9 @@ def run_cell(cell: dict, config: dict, traffic: dict, seed: int,
             jax.profiler.stop_trace()
         if window.t0 is None:
             raise RuntimeError("the run ended before its window opened")
-        ctx["setup_s"] = window.t0 - t_start
-        ctx["window_s"] = t_end - window.t0
+        t_open = window.t0
+        ctx["setup_s"] = t_open - t_start
+        ctx["window_s"] = t_end - t_open
         ctx["window_compiles"] = watch.n - setup_compiles[0]
         segs = [m for m in out["metrics"] if m["step"] >= warmup]
         ctx["segments"] = len(segs)
@@ -351,7 +367,7 @@ def run_cell(cell: dict, config: dict, traffic: dict, seed: int,
             for d in devs)
         # host spans inside the window, on the tracer's clock
         ctx["spans"] = [s for s in tel.spans()
-                        if s["t0"] >= window.t0 - tel.epoch] if trace else []
+                        if s["t0"] >= t_open - tel.epoch] if trace else []
         say("warm-up spikes per segment: "
             + " ".join(str(int(m["d_spikes"])) for m in out["metrics"]
                        if m["step"] < warmup))
@@ -372,9 +388,10 @@ def run_cell(cell: dict, config: dict, traffic: dict, seed: int,
             starts, min(traffic["check_segments"], len(starts)))
         pairs = [(a, a + seg) for a in sorted(picked)]
         like = abstract_dist_inputs(driver.dist_cfg, driver.storage)[0]
+        model = reference.Model.from_config(config)
         ctx["check_inputs"] = [
-            (flat_state(restore_checkpoint(ckpt_dir, a, like)),
-             flat_state(restore_checkpoint(ckpt_dir, b, like)),
+            (flat_state(restore_checkpoint(ckpt_dir, a, like), model),
+             flat_state(restore_checkpoint(ckpt_dir, b, like), model),
              {"spikes": by_step[a]["d_spikes"],
               "events": by_step[a]["d_events"]})
             for a, b in pairs]
@@ -386,13 +403,16 @@ def run_cell(cell: dict, config: dict, traffic: dict, seed: int,
     try:
         if trace:
             found = [str(p) for p in Path(trace_dir).rglob("*.xplane.pb")]
-            if found:
-                ctx["trace"] = trace_reduce.reduce(trace_reduce.load(found[0]))
+            profile = trace_reduce.load(found[0]) if found else None
+            if profile is not None:
+                ctx["trace"] = trace_reduce.reduce(profile)
+            ctx.update(spans.context(tel, t_open, profile))
+            del profile
     finally:
         shutil.rmtree(tmp, ignore_errors=True)
 
     t0 = time.perf_counter()
-    ctx["readings"] = compare(config, seg, ctx["check_inputs"])
+    ctx["readings"] = compare(config, seg, ctx["check_inputs"], devs)
     ctx["reference_s"] = time.perf_counter() - t0
     say(f"reference: {len(ctx['check_inputs'])} segment(s) in "
         f"{ctx['reference_s']:.1f} s")
@@ -400,23 +420,23 @@ def run_cell(cell: dict, config: dict, traffic: dict, seed: int,
     return ctx
 
 
-def compare(config: dict, seg: int, inputs, ring_dtype: str = "float32"
-            ) -> Dict[str, float]:
+def compare(config: dict, seg: int, inputs, devices,
+            ring_dtype: str = "float32") -> Dict[str, float]:
     """Worst reading of each compared number over the checked segments,
-    the reference run from each segment's checkpointed start."""
+    the reference run from each segment's checkpointed start, its
+    connectome one tile's block to a device of ``devices``."""
     import jax
     t0 = time.perf_counter()
     model = reference.Model.from_config(config)
-    syn = reference.connectome(model)
-    if len(syn["pre"]) != config["n_synapses"]:
-        raise Mismatch(f"the reference realizes {len(syn['pre'])} synapses,"
-                       f" the configuration states {config['n_synapses']}")
-    t1 = time.perf_counter()
-    dev_syn = jax.block_until_ready(reference.device_synapses(model, syn))
-    del syn
+    dev_syn = jax.block_until_ready(reference.device_synapses(
+        model, reference.connectome(model), devices))
+    n_syn = sum(int(b["pre"].shape[0]) for b in dev_syn["blocks"])
+    if n_syn != config["n_synapses"]:
+        raise Mismatch(f"the reference realizes {n_syn} synapses, the "
+                       f"configuration states {config['n_synapses']}")
     t2 = time.perf_counter()
-    say(f"reference connectome {t1 - t0:.1f} s, on the device "
-        f"{t2 - t1:.1f} s")
+    say(f"reference connectome, realized and on {len(dev_syn['blocks'])} "
+        f"device(s): {t2 - t0:.1f} s")
     segment = reference.make_segment(model, seg, ring_dtype)
     out = []
     for start, prog, prog_counts in inputs:

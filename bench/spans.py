@@ -10,8 +10,8 @@ instant.  Idle time with no span open, or inside the outer ``segment``
 span but none of its children, is unattributed: the program does not
 say what the host was doing.  ``context`` gives the two entries of a
 metric reader's ``ctx`` that the span metrics read: ``setup_spans``
-and ``span_idle``; ``bench/run.py:run_cell`` is where a traced run
-would call it, on the profile it already loads.
+and ``span_idle``; ``bench/run.py:run_cell`` calls it in a traced run,
+on the profile it loads for ``bench/trace.py``.
 """
 
 from __future__ import annotations
@@ -146,33 +146,11 @@ def main_line_spans(profile, names: Set[str]
     raise ValueError(f"no {trace.WINDOW!r} annotation in the trace")
 
 
-def device_busy(profile, t0: float, t1: float) -> List[List[Interval]]:
-    """Per TPU device plane, the union of its operations' intervals
-    clipped to ``[t0, t1)``: ``bench/trace.py``'s busy time, through its
-    own plane pattern and union, which it does not expose per device."""
-    out = []
-    for plane in profile.planes:
-        if not trace._DEVICE.match(plane.name):
-            continue
-        spans = []
-        for line in plane.lines:
-            if line.name != "XLA Ops":
-                continue
-            for ev in line.events:
-                s = max(ev.start_ns, t0)
-                e = min(ev.start_ns + ev.duration_ns, t1)
-                if e > s:
-                    spans.append((s, e))
-        if spans:
-            out.append(trace._union(spans))
-    return out
-
-
 def idle_by_span(profile, names: Set[str], top: int = 10) -> Optional[Dict]:
     """The window's device-idle time by program span, or None when no
     TPU plane holds an operation inside the window."""
     t0, t1 = trace.window_of(profile)
-    busy = device_busy(profile, t0, t1)
+    busy = trace.device_busy(profile, t0, t1)
     if not busy:
         return None
     return attribute(busy, main_line_spans(profile, names), t0, t1, top)

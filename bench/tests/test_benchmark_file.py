@@ -33,7 +33,8 @@ def test_every_entry_resolves():
         assert set(w) == {"name", "config", "traffic", "chips", "why"}
         assert w["config"] in configs and len(w["why"]) <= 200
         _, cell, cfg, traffic = run.load_cell(w["name"])
-        assert cfg["chips"] == w["chips"]
+        assert cfg["chips"] == w["chips"] == cfg["tiles"][0] * cfg["tiles"][1]
+        assert "halo_floor" in cfg["model"]
         assert traffic["warmup_steps"] % traffic["job"]["segment_steps"] == 0
     e2e = {m["name"] for m in b["end_to_end"]}
     assert "setup_s" in e2e

@@ -134,3 +134,19 @@ def test_span_readers_on_a_synthetic_context():
     assert read == pytest.approx({
         "table_sample_s": 7.5, "table_place_s": 1.5,
         "segment_sync_ms": 10.0, "idle_unattributed_share": 25.0})
+
+
+def test_traced_run_gives_the_span_readers_their_context(small):
+    """A traced run hands the set-up spans to the span readers; with no
+    TPU plane in the trace there is no device idle time to attribute."""
+    cell, config, traffic = small
+    ctx = run.run_cell(cell, config, traffic, 2 ** 31 + 5, seconds=1.0,
+                       trace=True, peaks={}, require_chip=False,
+                       t_start=time.perf_counter())
+    assert ctx["trace"] is None and ctx["span_idle"] is None
+    names = {s["name"] for s in ctx["setup_spans"]}
+    assert {"setup.tables", "setup.tables.sample",
+            "setup.tables.place"} <= names
+    assert run.reader("layer", "table_sample_s")(ctx) > 0
+    assert run.reader("layer", "table_place_s")(ctx) > 0
+    assert run.reader("layer", "idle_unattributed_share")(ctx) is None

@@ -44,7 +44,7 @@ def test_two_delivery_paths_are_charged_the_same(small):
     _, config, traffic = small
     spec = run.job_spec(config, traffic, seed=3, ckpt_dir="unused")
     cfg = dist_config_for(spec, (1, 1))
-    mesh = make_host_mesh()
+    mesh = make_host_mesh((1, 1))
     tables, _ = build_dist_tables(cfg)
     state_sh, table_sh = dist_shardings(cfg, mesh, tables.storage)
     inputs = SimInputs(tables=jax.device_put(tables, table_sh))

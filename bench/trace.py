@@ -4,13 +4,15 @@ The benchmark brackets its measured window with a host annotation
 (``WINDOW``); everything here is clipped to that annotation's interval.
 From the TPU device planes' ``XLA Ops`` lines it takes:
 
-* the device self time of each operation -- its time less that of the
+* the device self time of each operation, averaged over the device
+  planes -- its time less that of the
   operations nested in it, as the body of a ``while`` is in the loop --
   by name, read from the HLO text the event carries with the numeric
   suffix XLA adds stripped (``%fusion.12 = ...`` -> ``fusion``; a Pallas
   kernel keeps the name it was given, such as ``synaptic_accum``);
 * the busy time: the union of the intervals in which any operation ran,
-  averaged over the device planes;
+  averaged over the device planes (``device_busy`` gives each plane's
+  intervals);
 * the idle gaps between busy intervals, each named after the host event
   (``<thread>:<event>``) that overlaps it most -- what the host was
   doing while the device waited.
@@ -88,12 +90,11 @@ def window_of(profile) -> Tuple[float, float]:
     raise ValueError(f"no {WINDOW!r} annotation in the trace")
 
 
-def reduce(profile, top: int = 10) -> Optional[Dict]:
-    """Device times of the window, or None when no TPU plane holds an
-    operation inside it."""
-    t0, t1 = window_of(profile)
-    per_device = []
-    op_s: Dict[str, float] = {}
+def _device_ops(profile, t0: float, t1: float
+                ) -> List[List[Tuple[float, float, str]]]:
+    """Per TPU device plane with an operation inside ``[t0, t1)``, its
+    operations ``(start_ns, end_ns, name)`` clipped to it."""
+    out = []
     for plane in profile.planes:
         if not _DEVICE.match(plane.name):
             continue
@@ -107,9 +108,28 @@ def reduce(profile, top: int = 10) -> Optional[Dict]:
                 if e > s:
                     spans.append((s, e, op_name(ev.name)))
         if spans:
-            per_device.append(_union([(s, e) for s, e, _ in spans]))
-            for k, v in _self_times(spans).items():
-                op_s[k] = op_s.get(k, 0.0) + v
+            out.append(spans)
+    return out
+
+
+def device_busy(profile, t0: float, t1: float
+                ) -> List[List[Tuple[float, float]]]:
+    """Per TPU device plane with an operation inside ``[t0, t1)``, the
+    sorted, disjoint intervals (ns) in which one ran, clipped to it."""
+    return [_union([(s, e) for s, e, _ in spans])
+            for spans in _device_ops(profile, t0, t1)]
+
+
+def reduce(profile, top: int = 10) -> Optional[Dict]:
+    """Device times of the window, or None when no TPU plane holds an
+    operation inside it."""
+    t0, t1 = window_of(profile)
+    per_device = []
+    op_s: Dict[str, float] = {}
+    for spans in _device_ops(profile, t0, t1):
+        per_device.append(_union([(s, e) for s, e, _ in spans]))
+        for k, v in _self_times(spans).items():
+            op_s[k] = op_s.get(k, 0.0) + v
     if not per_device:
         return None
     busy = [sum(e - s for s, e in u) * 1e-9 for u in per_device]
